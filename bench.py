@@ -1849,14 +1849,18 @@ def _bench_body(_obs_reg):
         "vs_baseline": round(headline / cpu_rate, 3),
         "headline_rung": headline_rung,
     }
-    # environment fingerprint: every detail blob (and trend record)
-    # says WHERE it was measured, so a cross-host comparison can
-    # refuse instead of flagging hardware differences as regressions
+    # environment: every detail blob says WHERE it was measured, so
+    # a cross-host comparison can refuse instead of flagging hardware
+    # differences as regressions
     env = None
     try:
-        from jepsen_tpu.obs import trend as obs_trend
-        env = obs_trend.fingerprint()
-        obs_trend.record(rungs, fp=env, label="bench")
+        import os
+        import socket
+        devs = jax.devices()
+        env = {"hostname": socket.gethostname(),
+               "jax_platforms": os.environ.get("JAX_PLATFORMS"),
+               "jax": jax.__version__, "platform": devs[0].platform,
+               "device_count": len(devs)}
     except Exception:  # noqa: BLE001 - the headline must print
         pass
     # detail first, short headline-only line LAST: the driver captures

@@ -1,0 +1,11 @@
+"""dispatch.split_share: the share of the window inside the program's
+``independent.split`` span (``history_keys`` and every key's
+``subhistory``): the union of its intervals, clipped to the window,
+over the window. Read from the program's spans on the device trace's
+clock (``trace/attribute.py``); nothing where the run carries none."""
+
+from benchmark.trace import attribute
+
+
+def read(run):
+    return attribute.span_share(run.trace, "independent.split")

@@ -290,14 +290,17 @@ def _segment_diags(spec, client_hist, result, min_seg, checks):
 # ---------------------------------------------------------------------------
 # invalid verdicts: bounded independent cross-check
 
-def _quiet_replay(fn, *args):
+def _quiet_replay(span, fn, *args, **span_args):
     """Run an engine replay with the obs sinks suppressed for this
     context: certification re-searches are analysis overhead, and
     letting them bump wgl.searches / chunk counters would corrupt the
-    run's own search accounting (one logical search per check)."""
+    run's own search accounting (one logical search per check). The
+    replay's own span (``span``, cat ``analysis``) opens OUTSIDE that
+    suppression, so the time it takes is still named."""
     from .. import obs
-    with obs.sink_scope(None, None):
-        return fn(*args)
+    with obs.span(span, cat="analysis", **span_args):
+        with obs.sink_scope(None, None):
+            return fn(*args)
 
 
 def _linear_check(spec, e, init_state, budget):
@@ -328,7 +331,8 @@ def _cross_check_diags(spec, client_hist, e, init_state, result,
         # count mismatches fall back to the whole history; the
         # segment pass reports VC007 for them
     et, it = target
-    r = _quiet_replay(engine_fn, spec, et, it, budget)
+    r = _quiet_replay("certify.cross_check", engine_fn, spec, et, it,
+                      budget, engine=cross_name, scope=scope)
     v = r.get("valid")
     if v is True:
         diags.append(diag(
@@ -405,8 +409,10 @@ def _differential_diags(spec, client_hist, result, samples, budget,
         got = {}
         for nm in engines:
             try:
-                got[nm] = _quiet_replay(DIFF_ENGINES[nm], spec, e_s,
-                                        init_s, budget).get("valid")
+                got[nm] = _quiet_replay(
+                    "certify.differential", DIFF_ENGINES[nm], spec, e_s,
+                    init_s, budget, engine=nm,
+                    rows=segs[i].rows).get("valid")
             except Exception:  # noqa: BLE001 - coverage note, not fatal
                 logger.warning("differential engine %s crashed", nm,
                                exc_info=True)

@@ -108,14 +108,9 @@ Codes:
                  or a --device-mem-budget with neither a --capacity
                  mode nor --device-slots auto (the knob is ignored)
                  -- warnings
-  PL022 mixed    phase attribution / perf trend gate: phase spans
-                 disabled (phases? False) while --profile or a bubble
-                 fold needs them to attribute idle time, an unreadable
-                 --trend-baseline file, or a non-positive /
-                 non-numeric --trend-gate-threshold -- errors; a
-                 trend baseline recorded under a different
-                 environment fingerprint than this host (the gate
-                 would refuse to compare at run time) -- warning
+  PL022 error    phase attribution: phase spans disabled (phases?
+                 False) while --profile or a bubble fold needs them
+                 to attribute idle time
   PL023 mixed    verdict certification (analysis/certify.py): a
                  non-positive / non-integer certify sample count or
                  cross-check budget -- errors; certify knobs set
@@ -177,7 +172,7 @@ logger = logging.getLogger(__name__)
 
 __all__ = ["lint_plan", "lint_campaign", "lint_fleet", "lint_service",
            "lint_telemetry", "lint_fleetlint", "lint_introspection",
-           "lint_coalesce", "lint_capacity", "lint_trend",
+           "lint_coalesce", "lint_capacity", "lint_phases",
            "lint_certify", "lint_ha", "preflight",
            "PlanLintError", "FATAL_CODES", "FLEETLINT_MODES",
            "monitor_diags", "searchplan_diags"]
@@ -352,8 +347,8 @@ def lint_plan(test):
     # -- device-introspection knobs (obs.search / obs.profile) ---------
     diags += lint_introspection(test)
 
-    # -- phase-attribution / trend-gate knobs (obs.phases / obs.trend) -
-    diags += lint_trend(test)
+    # -- phase-attribution knobs (obs.phases) -------------------------
+    diags += lint_phases(test)
 
     # -- verdict-certification knobs (analysis/certify.py) -------------
     diags += lint_certify(test)
@@ -444,10 +439,10 @@ def lint_introspection(cfg):
     return diags
 
 
-def lint_trend(cfg):
+def lint_phases(cfg):
     """The PL022 rules over a test map's (or option map's) phase
-    attribution and perf-trend-gate wiring. Works on plain option
-    dicts too — the fleet dispatcher runs it over base options."""
+    attribution wiring. Works on plain option dicts too — the fleet
+    dispatcher runs it over base options."""
     diags = []
     if not isinstance(cfg, dict):
         return diags
@@ -469,51 +464,6 @@ def lint_trend(cfg):
                 "from wgl.phase.* spans and would attribute nothing",
                 "plan.phases",
                 "drop phases? False, or drop bubbles?"))
-    baseline = cfg.get("trend-baseline")
-    if baseline is not None:
-        import os
-        bp = str(baseline)
-        if not (os.path.isfile(bp) and os.access(bp, os.R_OK)):
-            diags.append(diag(
-                "PL022", ERROR,
-                f"trend-baseline {bp!r} is not a readable file: the "
-                "perf gate has nothing to compare against",
-                "plan.trend-baseline",
-                "point trend-baseline at a trend.jsonl written by "
-                "'python -m jepsen_tpu.obs.trend record'"))
-        else:
-            try:
-                from ..obs import trend as obs_trend
-                records = obs_trend.load(bp)
-                here = obs_trend.fingerprint()
-                mismatched = [r for r in records
-                              if r.get("fingerprint")
-                              and r["fingerprint"] != here]
-                if records and len(mismatched) == len(records):
-                    diags.append(diag(
-                        "PL022", WARNING,
-                        "every trend-baseline record carries a "
-                        "different environment fingerprint than this "
-                        "host: the gate will refuse to compare "
-                        "(regressions measured on different hardware "
-                        "or jax builds are not regressions)",
-                        "plan.trend-baseline",
-                        "re-record the baseline on this host"))
-            except Exception:  # noqa: BLE001
-                logger.debug("couldn't fingerprint trend baseline",
-                             exc_info=True)
-    thresh = cfg.get("trend-gate-threshold")
-    if thresh is not None and (not isinstance(thresh, (int, float))
-                               or isinstance(thresh, bool)
-                               or thresh <= 0):
-        diags.append(diag(
-            "PL022", ERROR,
-            f"trend-gate-threshold should be a positive fraction, "
-            f"got {thresh!r}: a non-positive allowance would flag "
-            "every quiet-floor wiggle as a regression",
-            "plan.trend-gate-threshold",
-            "use a fraction like 0.2, or drop the knob for the "
-            "default"))
     return diags
 
 

@@ -290,10 +290,9 @@ def run_fleet(cells, workers, *, campaign_id=None, resume=False,
     # PL018 (knob half): an unknown --fleetlint value is an error
     # here, not a silently-skipped audit
     diags += planlint.lint_fleetlint({"fleetlint": fleetlint})
-    # PL022: phase-attribution / trend-gate knobs ride along like
-    # PL019 (phases off while profile or a bubble fold needs their
-    # spans, unreadable trend baselines, bad gate thresholds)
-    diags += planlint.lint_trend(base_options)
+    # PL022: phase-attribution knobs ride along like PL019 (phases
+    # off while profile or a bubble fold needs their spans)
+    diags += planlint.lint_phases(base_options)
     # PL023: verdict-certification knobs ride along the same way (bad
     # sample counts / budgets; the skip-offline? backstop note)
     diags += planlint.lint_certify(base_options)

@@ -21,6 +21,7 @@ from dataclasses import dataclass, replace
 
 from . import generator as gen
 from . import history as h
+from . import obs
 from .checker.core import Checker, as_checker, check_safe, merge_valid
 from .util import bounded_pmap
 
@@ -249,8 +250,11 @@ class _IndependentChecker(Checker):
 
     def check(self, test, history, opts=None):
         opts = opts or {}
-        ks = sorted(history_keys(history), key=repr)
-        subs = {k: subhistory(k, history) for k in ks}
+        with obs.span("independent.split", cat="dispatch") as sp:
+            ks = sorted(history_keys(history), key=repr)
+            subs = {k: subhistory(k, history) for k in ks}
+            if sp is not None:
+                sp["keys"] = len(ks)
 
         fast = self._check_batched(test, ks, subs, opts)
         if fast is not None:
@@ -364,47 +368,33 @@ class _IndependentChecker(Checker):
             # differently and could diverge on exotic process values
             pairs = []
             spans = []          # per key: (start, count, info, plan_s)
-            for k in ks:
-                client = lin.prepare_history(h.client_ops(subs[k]))
-                segs, info, plan_s = None, None, 0.0
-                if plan_on:
-                    # sealed quiescent cuts slice each key's history
-                    # into independent segments; they all ride the
-                    # SAME batch, so the key axis and the segment axis
-                    # share one compiled kernel per shape bucket
-                    t0 = _time.monotonic()
-                    segs, info = searchplan.plan_segments(
-                        lin.spec, client, min_seg)
-                    plan_s = _time.monotonic() - t0
-                    if len(segs) < 2:
-                        segs = None     # no reduction: encode as-is
-                start = len(pairs)
-                if segs is None:
-                    pairs.append(lin.spec.encode(client))
-                    spans.append((start, 1, None, 0.0, None))
-                else:
-                    pairs.extend(lin.spec.encode(s.events)
-                                 for s in segs)
-                    spans.append((start, len(segs), info, plan_s,
-                                  [s.seed for s in segs]))
+            with obs.span("independent.prepare", cat="dispatch") as sp:
+                for k in ks:
+                    client = lin.prepare_history(h.client_ops(subs[k]))
+                    segs, info, plan_s = None, None, 0.0
+                    if plan_on:
+                        # sealed quiescent cuts slice each key's history
+                        # into independent segments; they all ride the
+                        # SAME batch, so the key axis and the segment
+                        # axis share one compiled kernel per shape bucket
+                        t0 = _time.monotonic()
+                        segs, info = searchplan.plan_segments(
+                            lin.spec, client, min_seg)
+                        plan_s = _time.monotonic() - t0
+                        if len(segs) < 2:
+                            segs = None     # no reduction: encode as-is
+                    start = len(pairs)
+                    if segs is None:
+                        pairs.append(lin.spec.encode(client))
+                        spans.append((start, 1, None, 0.0, None))
+                    else:
+                        pairs.extend(lin.spec.encode(s.events)
+                                     for s in segs)
+                        spans.append((start, len(segs), info, plan_s,
+                                      [s.seed for s in segs]))
+                if sp is not None:
+                    sp.update(keys=len(ks), pairs=len(pairs))
             batch = check_batch_encoded(lin.spec, pairs, **lin.engine_opts)
-            per_key = []
-            for start, count, info, plan_s, seeds in spans:
-                if count == 1 and info is None:
-                    per_key.append(batch[start])
-                else:
-                    # stamp segment provenance onto each normalized
-                    # witness before the merge folds them, exactly like
-                    # Linearizable._check_planned: the verdict certifier
-                    # re-derives the same cuts and matches
-                    # index/count/seed
-                    for i in range(count):
-                        w = batch[start + i].get("witness")
-                        if isinstance(w, dict):
-                            w["segment"] = {"index": i, "count": count,
-                                            "seed": seeds[i]}
-                    per_key.append(searchplan.merge_segment_results(
-                        batch[start:start + count], info, plan_s))
         except Exception:  # noqa: BLE001 - fall back to per-key path
             logger.warning("batched independent check failed; falling back",
                            exc_info=True)
@@ -435,7 +425,14 @@ class _IndependentChecker(Checker):
             self._write_key_files(test, subdir, r, subs[k])
             return k, r
 
-        return dict(bounded_pmap(finish, list(zip(ks, per_key))))
+        with obs.span("independent.finish", cat="dispatch", keys=len(ks)):
+            try:
+                per_key = _merge_segments(batch, spans)
+            except Exception:  # noqa: BLE001 - fall back to per-key path
+                logger.warning("batched independent check failed; "
+                               "falling back", exc_info=True)
+                return None
+            return dict(bounded_pmap(finish, list(zip(ks, per_key))))
 
     def _write_key_files(self, test, subdir, results, sub):
         """Per-key results.json + history.txt in the store
@@ -453,6 +450,29 @@ class _IndependentChecker(Checker):
                     f.write(op_str(op) + "\n")
         except Exception:  # noqa: BLE001 - persistence is best-effort here
             logger.warning("couldn't write per-key files", exc_info=True)
+
+
+def _merge_segments(batch, spans):
+    """Each key's result from the batch: its one result, or its
+    segments' results merged."""
+    from .analysis import searchplan
+    per_key = []
+    for start, count, info, plan_s, seeds in spans:
+        if count == 1 and info is None:
+            per_key.append(batch[start])
+        else:
+            # stamp segment provenance onto each normalized witness
+            # before the merge folds them, exactly like
+            # Linearizable._check_planned: the verdict certifier
+            # re-derives the same cuts and matches index/count/seed
+            for i in range(count):
+                w = batch[start + i].get("witness")
+                if isinstance(w, dict):
+                    w["segment"] = {"index": i, "count": count,
+                                    "seed": seeds[i]}
+            per_key.append(searchplan.merge_segment_results(
+                batch[start:start + count], info, plan_s))
+    return per_key
 
 
 def checker(inner):

@@ -17,8 +17,13 @@ contextvar (trace._span_stack), so parentage follows the
 `contextvars.copy_context()` snapshots the thread fan-outs already
 take.
 
-Every facade function below is a safe no-op while nothing is bound:
-off-by-default, one global read + falsy check per call site, so the
+Every facade function below finds its sinks by one rule,
+`current_sinks()`: the pair `sink_scope` pinned for this logical
+context when there is one, else the `bind()` globals. So a monitor
+thread, the benchmark's traced window or a certification replay that
+pins its own pair (or none, ``sink_scope(None, None)``) steers spans,
+counters and histograms alike. Each is a safe no-op while nothing is
+bound: one contextvar read + falsy check per call site, so the
 uninstrumented hot paths pay nothing measurable. `core.run` binds a
 fresh pair per test run (opt out with ``test["obs?"] = False``) and
 store.py persists both artifacts next to ``results.json``.
@@ -82,10 +87,10 @@ def registry():
 def current_sinks():
     """(tracer, registry) for THIS logical context: the run-scoped
     pair when inside a run (correct even while a sibling campaign
-    cell holds the process-global binding), else the globals. The
-    device search sessions (obs.search.capture) resolve through this
-    so two concurrent cells' heartbeat counters stop folding into one
-    series."""
+    cell holds the process-global binding), else the globals. Every
+    facade function and the device search and phase sessions resolve
+    through this, so two concurrent cells' counters stop folding into
+    one series and a ``sink_scope`` steers all of them alike."""
     ctx = _ctx_sinks.get()
     if ctx is not None:
         return ctx[0], ctx[1]
@@ -103,9 +108,10 @@ def run_config():
 def sink_scope(tr, reg, config=None):
     """Pin (tracer, registry) as THIS context's run-scoped sinks
     without touching the process-global binding — how a thread that
-    captured its run's pair at construction (the monitor) makes the
-    search sessions it drives resolve that pair instead of whatever
-    the globals currently say."""
+    captured its run's pair at construction (the monitor) makes
+    everything it drives (the facade, the search and phase sessions)
+    resolve that pair instead of whatever the globals currently say;
+    ``sink_scope(None, None)`` silences all of them."""
     token = _ctx_sinks.set((tr, reg, dict(config or {})))
     try:
         yield (tr, reg)
@@ -127,8 +133,19 @@ def live_registries():
     return out
 
 
+def _active_tracer():
+    ctx = _ctx_sinks.get()
+    return _tracer if ctx is None else ctx[0]
+
+
+def _active_registry():
+    ctx = _ctx_sinks.get()
+    return _registry if ctx is None else ctx[1]
+
+
 def enabled():
-    return _tracer is not None or _registry is not None
+    tr, reg = current_sinks()
+    return tr is not None or reg is not None
 
 
 @contextlib.contextmanager
@@ -202,51 +219,53 @@ def run_scope(test):
 # tracing facade (no-ops while unbound)
 
 def now_ns():
-    tr = _tracer
+    tr = _active_tracer()
     return tr.now_ns() if tr is not None else _time.monotonic_ns()
 
 
 def span(name, cat="lifecycle", tid=None, **args):
-    """Context manager: a nested trace span (no-op while unbound)."""
-    tr = _tracer
+    """Context manager: a nested trace span (no-op while unbound). It
+    yields the span's args dict, for args the body learns, or None
+    while unbound."""
+    tr = _active_tracer()
     if tr is None:
         return contextlib.nullcontext()
     return tr.span(name, cat=cat, tid=tid, args=args or None)
 
 
 def instant(name, cat="default", tid=None, **args):
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.instant(name, cat=cat, tid=tid, args=args or None)
 
 
 def complete(name, ts_ns, dur_ns, cat="default", tid=None, **args):
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.complete(name, ts_ns, dur_ns, cat=cat, tid=tid,
                     args=args or None)
 
 
 def counter_track(name, cat="default", **values):
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.counter(name, values, cat=cat)
 
 
 def window_start(name, wid, cat="nemesis", **args):
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.async_begin(name, wid, cat=cat, args=args or None)
 
 
 def window_end(name, wid, cat="nemesis", **args):
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.async_end(name, wid, cat=cat, args=args or None)
 
 
 def name_thread(tid, name):
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.name_thread(tid, name)
 
@@ -255,7 +274,7 @@ def flush(force_metrics=True):
     """Force the bound sinks' journals to disk (no-op when unbound or
     unjournaled): the facade for code that just produced something a
     crash must not lose."""
-    tr, reg = _tracer, _registry
+    tr, reg = current_sinks()
     if tr is not None:
         tr.flush_journal()
     if reg is not None and force_metrics:
@@ -267,7 +286,7 @@ def gen_event(tag, kind, payload):
     traced op/update, alongside its existing log line. The repr is
     capped like every other instrumentation site — traced generators
     over large values must not bloat the event buffer."""
-    tr = _tracer
+    tr = _active_tracer()
     if tr is not None:
         tr.instant(f"gen.{tag}", cat="generator",
                    args={"kind": kind, "event": repr(payload)[:200]})
@@ -277,31 +296,31 @@ def gen_event(tag, kind, payload):
 # metrics facade (no-ops while unbound)
 
 def inc(name, n=1, **labels):
-    reg = _registry
+    reg = _active_registry()
     if reg is not None:
         reg.inc(name, n, **labels)
 
 
 def set_gauge(name, value, **labels):
-    reg = _registry
+    reg = _active_registry()
     if reg is not None:
         reg.set_gauge(name, value, **labels)
 
 
 def max_gauge(name, value, **labels):
-    reg = _registry
+    reg = _active_registry()
     if reg is not None:
         reg.max_gauge(name, value, **labels)
 
 
 def observe(name, value, buckets=None, **labels):
-    reg = _registry
+    reg = _active_registry()
     if reg is not None:
         reg.observe(name, value, buckets=buckets, **labels)
 
 
 def observe_many(name, values, buckets=None, **labels):
     """Batch form of `observe`: one lock acquisition for the lot."""
-    reg = _registry
+    reg = _active_registry()
     if reg is not None:
         reg.observe_many(name, values, buckets=buckets, **labels)

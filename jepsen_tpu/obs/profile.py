@@ -32,6 +32,14 @@ NEXT TO ``trace.jsonl``:
   with the reason; the run itself NEVER fails because profiling
   could not (the CI profile smoke pins this).
 
+* **One clock.** The capture runs inside `anchor` (``ANCHOR``): one
+  ``TraceAnnotation`` in the profile and one span of the same name in
+  ``trace.jsonl``, opened back to back. `on_profile_clock` maps every
+  span of ``trace.jsonl`` onto the profile's clock by the difference
+  of the two starts (error: the few microseconds between the two
+  enters), so a device idle gap in the capture can be put down to the
+  program span that covers it.
+
 ``JEPSEN_NO_PROFILER=1`` forces `available()` False — how the
 containment path is exercised deterministically in CI.
 """
@@ -47,8 +55,9 @@ import time as _time
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["available", "scope", "profile_dir_for", "MARKER_FILE",
-           "PROFILE_DIR", "DEFAULT_MAX_S"]
+__all__ = ["available", "scope", "anchor", "on_profile_clock",
+           "profile_dir_for", "MARKER_FILE", "PROFILE_DIR",
+           "DEFAULT_MAX_S", "ANCHOR"]
 
 #: subdirectory of the run dir the capture lands in
 PROFILE_DIR = "profile"
@@ -57,6 +66,8 @@ MARKER_FILE = "profile.json"
 #: capture wall bound: a wedged search must not grow the capture
 #: forever (the stop timer fires mid-search and the run continues)
 DEFAULT_MAX_S = 120.0
+#: the anchor `scope` opens around the captured body
+ANCHOR = "obs.profile"
 
 #: the profiler is process-global state; a second concurrent capture
 #: (overlapping campaign cells) must refuse, not corrupt the first
@@ -75,6 +86,34 @@ def available():
             and callable(getattr(_p, "stop_trace", None))
     except Exception:  # noqa: BLE001 - no jax / broken install
         return False
+
+
+@contextlib.contextmanager
+def anchor(name):
+    """A ``jax.profiler.TraceAnnotation`` and a span of the bound tracer
+    (cat ``anchor``), both named ``name``, opened back to back: the pair
+    `on_profile_clock` maps one clock onto the other by."""
+    import jax
+    from .. import obs
+    with jax.profiler.TraceAnnotation(name), obs.span(name, cat="anchor"):
+        yield
+
+
+def on_profile_clock(events, name, profile_start_ns):
+    """The complete (``X``) events of a tracer (``Tracer.events()`` or a
+    loaded ``trace.jsonl``) as ``(name, cat, start_ns, dur_ns)`` on the
+    profiler's clock, given where the profiler put the start of the
+    `anchor` called ``name``. None when ``events`` hold no such
+    anchor."""
+    starts = [ev["ts"] for ev in events
+              if ev.get("ph") == "X" and ev.get("name") == name
+              and ev.get("cat") == "anchor"]
+    if not starts:
+        return None
+    off = profile_start_ns - starts[0] * 1e3
+    return [(ev["name"], ev.get("cat"), ev["ts"] * 1e3 + off,
+             ev.get("dur", 0.0) * 1e3)
+            for ev in events if ev.get("ph") == "X"]
 
 
 def profile_dir_for(test):
@@ -230,7 +269,12 @@ def scope(test):
         logger.warning("profiler setup failed", exc_info=True)
     t0 = _time.monotonic()
     try:
-        yield pdir if started else None
+        if started:
+            state["anchor"] = ANCHOR
+            with anchor(ANCHOR):
+                yield pdir
+        else:
+            yield None
     finally:
         if timer is not None:
             timer.cancel()

@@ -329,15 +329,17 @@ class Tracer:
         """A nested span: records an ``X`` event on exit and pushes the
         name onto the contextvar parent stack for the duration, so spans
         opened inside (including in threads spawned from a context
-        snapshot taken inside) carry ``args.parent``."""
+        snapshot taken inside) carry ``args.parent``. Yields the span's
+        args dict: the body may add what it learns (a count known only
+        at the end)."""
         stack = _span_stack.get()
         token = _span_stack.set(stack + (name,))
+        a = dict(args or {})
         t0 = self.now_ns()
         try:
-            yield
+            yield a
         finally:
             _span_stack.reset(token)
-            a = dict(args or {})
             if stack:
                 a["parent"] = stack[-1]
             self.complete(name, t0, self.now_ns() - t0, cat=cat,

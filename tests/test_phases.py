@@ -1,5 +1,5 @@
-"""Per-dispatch phase attribution (obs.phases), the idle-bubble
-ledger (obs.bubbles), and the noise-aware perf trend gate (obs.trend).
+"""Per-dispatch phase attribution (obs.phases) and the idle-bubble
+ledger (obs.bubbles).
 
 The invariants pinned here are the ones the whole "where does the
 wall go?" plane rests on:
@@ -13,19 +13,16 @@ wall go?" plane rests on:
   are measured and never exceeds the full dispatch-chunk wall
   (``wgl.chunk_s``) — the busy-honesty fix;
 * bubble re-folds are byte-identical and the attribution math adds
-  up;
-* the trend comparator passes A/A, catches real drops, and refuses
-  cross-environment baselines.
+  up.
 """
 
-import json
 import time
 
 import pytest
 
 from jepsen_tpu import obs
 from jepsen_tpu.models import cas_register_spec
-from jepsen_tpu.obs import bubbles, trend
+from jepsen_tpu.obs import bubbles
 from jepsen_tpu.obs import phases as obs_phases
 from jepsen_tpu.obs import search as obs_search
 from jepsen_tpu.simulate import random_history
@@ -270,81 +267,12 @@ def test_bubble_fold_ignores_non_phase_events():
 
 
 # ---------------------------------------------------------------------------
-# trend gate
-
-def _rec(best_samples, fp=None, rung="mini-cas-batch"):
-    return {"t": 0, "fingerprint": fp or {"host": "a"},
-            "rungs": {rung: {"metrics": {"ops_per_s":
-                                         max(best_samples)},
-                             "samples": {"ops_per_s":
-                                         list(best_samples)}}}}
-
-
-def test_trend_compare_quiet_floor():
-    base = [_rec([100.0, 90.0]), _rec([98.0, 95.0])]
-    # within the allowance (threshold 0.2 > measured noise 0.1)
-    ok = trend.compare(base, _rec([85.0]))
-    assert ok["compared"] == 1 and ok["regressions"] == []
-    # a real drop: 60 < 100 * (1 - 0.2)
-    bad = trend.compare(base, _rec([60.0]))
-    assert len(bad["regressions"]) == 1
-    r = bad["regressions"][0]
-    assert r["metric"] == "ops_per_s"
-    assert r["drop_frac"] == pytest.approx(0.4)
-    # a noisy baseline widens its own allowance past the threshold
-    noisy = [_rec([100.0, 50.0])]
-    assert trend.compare(noisy, _rec([55.0]))["regressions"] == []
-
-
-def test_trend_refuses_cross_environment_baselines():
-    base = [_rec([100.0], fp={"host": "elsewhere"})]
-    v = trend.compare(base, _rec([10.0], fp={"host": "here"}))
-    assert v["regressions"] == [] and v["compared"] == 0
-    assert v["baseline_records"] == 0
-    assert v["skipped_mismatched_env"] == 1
-
-
-def test_trend_record_load_and_gate_cli(tmp_path):
-    p = str(tmp_path / "trend.jsonl")
-    fp = {"host": "a"}
-    trend.record(_rec([100.0, 95.0])["rungs"], path=p, fp=fp)
-    trend.record(_rec([99.0])["rungs"], path=p, fp=fp, label="aa")
-    recs = trend.load(p)
-    assert len(recs) == 2 and recs[1]["label"] == "aa"
-    assert recs[0]["fingerprint"] == fp
-    assert trend.main(["gate", "--path", p]) == 0
-    trend.record(_rec([40.0])["rungs"], path=p, fp=fp)
-    assert trend.main(["gate", "--path", p]) == 1
-    # < 2 records: refused, NOT failed (a fresh repo must gate clean)
-    assert trend.main(["gate", "--path",
-                       str(tmp_path / "empty.jsonl")]) == 0
-
-
-def test_mini_bench_shape():
-    rungs = trend.mini_bench(n_keys=2, n_ops=40, repeats=2)
-    r = rungs["mini-cas-batch"]
-    assert len(r["samples"]["ops_per_s"]) == 2
-    assert r["metrics"]["ops_per_s"] == max(r["samples"]["ops_per_s"])
-    assert 0.0 <= r["metrics"]["duty_cycle"] <= 1.0
-    assert set(r["phase_s"]) <= set(obs_phases.PHASES)
-    assert "device" in r["phase_s"]
-
-
-def test_fingerprint_is_stable_and_jsonable():
-    a, b = trend.fingerprint(), trend.fingerprint()
-    assert a == b
-    json.dumps(a)
-    assert set(a) == {"hostname", "jax_platforms", "jax", "platform",
-                      "device_count"}
-
-
-# ---------------------------------------------------------------------------
 # PL022
 
-def test_pl022_lint_trend(tmp_path):
+def test_pl022_lint_trend():
     from jepsen_tpu.analysis import planlint
 
-    codes = planlint.lint_trend
+    codes = planlint.lint_phases
 
     assert codes({}) == []
     # phases off while a consumer needs the spans
@@ -354,26 +282,6 @@ def test_pl022_lint_trend(tmp_path):
     assert all(d.code == "PL022" and d.severity == "error"
                for d in errs)
     assert codes({"phases?": True, "profile?": True}) == []
-    # unreadable baseline
-    missing = str(tmp_path / "nope.jsonl")
-    errs = codes({"trend-baseline": missing})
-    assert len(errs) == 1 and errs[0].severity == "error"
-    # readable baseline from another environment: warning
-    p = tmp_path / "trend.jsonl"
-    p.write_text(json.dumps(
-        {"t": 0, "fingerprint": {"hostname": "not-this-box"},
-         "rungs": {}}) + "\n")
-    warns = codes({"trend-baseline": str(p)})
-    assert len(warns) == 1 and warns[0].severity == "warning"
-    # same-environment baseline lints clean
-    p.write_text(json.dumps(
-        {"t": 0, "fingerprint": trend.fingerprint(),
-         "rungs": {}}) + "\n")
-    assert codes({"trend-baseline": str(p)}) == []
-    # bad threshold
-    for bad in (0, -1, "fast", True):
-        assert codes({"trend-gate-threshold": bad}), bad
-    assert codes({"trend-gate-threshold": 0.3}) == []
     # and lint_plan carries the pass (the fleet/campaign wiring)
     t = {"name": "x", "phases?": False, "profile?": True}
     assert any(d.code == "PL022" for d in planlint.lint_plan(t))

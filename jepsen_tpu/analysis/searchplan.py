@@ -356,28 +356,13 @@ def estimate_configs(events):
 def per_key_parts(events):
     """The jepsen.independent per-key split: applicable when op values
     carry [k v] tuples. Returns {key: subhistory} with tuples
-    unwrapped, or None when no op is keyed. Semantics match
-    ``independent.subhistory`` (un-keyed ops replicate into every
-    part) but in ONE pass over the history — the per-key walk is
-    O(n*k) and measurably dominated a 600-key plan."""
+    unwrapped and keys sorted by repr, or None when no op is keyed:
+    ``independent.split`` over the history's keys."""
     from .. import independent
-    keyed = {}
-    unkeyed = []
-    for pos, op in enumerate(events):
-        v = op.get("value")
-        if independent.is_tuple(v):
-            op = dict(op)
-            op["value"] = v.value
-            keyed.setdefault(v.key, []).append((pos, op))
-        else:
-            unkeyed.append((pos, op))
-    if not keyed:
+    keys = independent.history_keys(events)
+    if not keys:
         return None
-    out = {}
-    for k in sorted(keyed, key=repr):
-        merged = sorted(keyed[k] + unkeyed, key=lambda po: po[0])
-        out[k] = [op for _, op in merged]
-    return out
+    return independent.split(events, sorted(keys, key=repr))
 
 
 def per_value_parts(events):

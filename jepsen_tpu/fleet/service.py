@@ -1037,7 +1037,7 @@ def _split_keyed(hist):
     if not keys:
         raise ApiError(400, "keyed check requested but no op carries "
                             "an [key, value] tuple value")
-    return {k: independent.subhistory(k, hist) for k in keys}
+    return independent.split(hist, keys)
 
 
 def check_history(payload, caller="local"):

@@ -240,6 +240,36 @@ def subhistory(k, history):
     return out
 
 
+def split(history, keys):
+    """``{k: subhistory(k, history) for k in keys}``, in one walk of the
+    history. Events of keys outside ``keys`` are dropped."""
+    return _split(history, keys)[0]
+
+
+def _split(history, keys):
+    """``split``, plus the number of un-keyed events every subhistory
+    carries. Buckets fill in event order: a keyed event is copied with
+    its value unwrapped into its key's bucket, an un-keyed one (the
+    same object) is appended to every bucket. O(events + keys x
+    un-keyed events), where a walk per key is O(events x keys)."""
+    subs = {k: [] for k in keys}
+    buckets = list(subs.values())
+    unkeyed = 0
+    for op in history:
+        v = op.get("value")
+        if not is_tuple(v):
+            unkeyed += 1
+            for sub in buckets:
+                sub.append(op)
+            continue
+        sub = subs.get(v.key)
+        if sub is not None:
+            op = dict(op)
+            op["value"] = v.value
+            sub.append(op)
+    return subs, unkeyed
+
+
 class _IndependentChecker(Checker):
     """Lifts a checker over plain values to one over [k v] histories
     (independent.clj:293-344). The linearizable fast path batches every
@@ -252,9 +282,10 @@ class _IndependentChecker(Checker):
         opts = opts or {}
         with obs.span("independent.split", cat="dispatch") as sp:
             ks = sorted(history_keys(history), key=repr)
-            subs = {k: subhistory(k, history) for k in ks}
+            subs, unkeyed = _split(history, ks)
             if sp is not None:
-                sp["keys"] = len(ks)
+                sp.update(keys=len(ks), events=len(history),
+                          unkeyed=unkeyed)
 
         fast = self._check_batched(test, ks, subs, opts)
         if fast is not None:

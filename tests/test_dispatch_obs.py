@@ -153,6 +153,8 @@ def test_independent_spans_on_the_batched_path():
         "independent.finish", "independent.prepare", "independent.split"]
     sp = _dispatch_spans(tr)
     assert sp["independent.split"]["args"]["keys"] == 3
+    assert sp["independent.split"]["args"]["events"] == 12
+    assert sp["independent.split"]["args"]["unkeyed"] == 0
     assert sp["independent.prepare"]["args"]["keys"] == 3
     assert sp["independent.prepare"]["args"]["pairs"] >= 3
     assert sp["independent.finish"]["args"]["keys"] == 3
@@ -172,10 +174,14 @@ def test_independent_spans_on_the_fallback_path():
                                              "algorithm": "wgl"}))
     tr, reg = obs.Tracer(), obs.Registry()
     with obs.bind(tr, reg):
-        r = cc.check(c, {}, _keyed_history(["a", "b"], bad_keys={"a"}))
+        r = cc.check(c, {}, [h.op("info", "nemesis", "kill", None)]
+                     + _keyed_history(["a", "b"], bad_keys={"a"}))
     assert r["valid"] is False
     assert _names(tr, "dispatch") == ["independent.split"]
-    assert _dispatch_spans(tr)["independent.split"]["args"]["keys"] == 2
+    args = _dispatch_spans(tr)["independent.split"]["args"]
+    assert args["keys"] == 2
+    assert args["events"] == 9
+    assert args["unkeyed"] == 1
     assert _names(tr).count("analysis.certify") == 1
 
 

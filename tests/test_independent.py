@@ -47,6 +47,84 @@ def test_history_keys_and_subhistory():
     assert [o.get("value") for o in sub] == [1, "whoops", 1]
 
 
+def _nem(f):
+    return h.op("info", "nemesis", f, None)
+
+
+_SPLIT_CASES = {
+    "unkeyed-before-between-after": (
+        [_nem("start"), inv(0, "w", T("a", 1)), _nem("kill"),
+         inv(1, "w", T("b", 2)), ok(0, "w", T("a", 1)), _nem("heal"),
+         ok(1, "w", T("b", 2)), _nem("stop")], None),
+    "no-unkeyed": (
+        [inv(0, "w", T("a", 1)), inv(1, "w", T("b", 2)),
+         ok(1, "w", T("b", 2)), ok(0, "w", T("a", 1))], None),
+    "one-key": (
+        [_nem("start"), inv(0, "w", T(7, 1)), ok(0, "w", T(7, 1))], None),
+    "mixed-key-types": (
+        [inv(0, "w", T(10, 1)), inv(1, "w", T("x", 2)), _nem("kill"),
+         inv(2, "w", T(2, 3)), inv(3, "w", T((1, "y"), 4)),
+         ok(2, "w", T(2, 3)), ok(0, "w", T(10, 1))], None),
+    "plain-2-list-stays-unkeyed": (
+        [inv(0, "w", T("a", 1)), inv(1, "w", ["b", 2]),
+         ok(1, "w", ["b", 2]), ok(0, "w", T("a", 1))], None),
+    "key-subset-drops-others": (
+        [inv(0, "w", T("a", 1)), _nem("kill"), inv(1, "w", T("b", 2)),
+         inv(2, "w", T("c", 3)), ok(1, "w", T("b", 2)),
+         ok(2, "w", T("c", 3)), ok(0, "w", T("a", 1))], ["c", "a"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SPLIT_CASES))
+def test_split_equals_subhistory_per_key(case):
+    hist, keys = _SPLIT_CASES[case]
+    if keys is None:
+        keys = sorted(independent.history_keys(hist), key=repr)
+    got = independent.split(hist, keys)
+    assert list(got) == list(keys)
+    assert got == {k: independent.subhistory(k, hist) for k in keys}
+    events = {id(o) for o in hist}
+    unkeyed = {id(o) for o in hist
+               if not independent.is_tuple(o.get("value"))}
+    for sub in got.values():
+        # un-keyed events are shared, keyed ones are unwrapped copies
+        assert all((id(o) in unkeyed) == (id(o) in events) for o in sub)
+        assert sum(id(o) in unkeyed for o in sub) == len(unkeyed)
+
+
+class _CountingHistory(list):
+    """A history that counts the walks over it."""
+    walks = 0
+
+    def __iter__(self):
+        self.walks += 1
+        return super().__iter__()
+
+
+@pytest.mark.parametrize("n_keys", [1, 16, 256])
+def test_split_walks_history_at_most_twice(n_keys):
+    events = [_nem("start")]
+    for k in range(n_keys):
+        events += [inv(k, "w", T(k, 1)), ok(k, "w", T(k, 1))]
+    hist = _CountingHistory(events)
+    ks = sorted(independent.history_keys(hist), key=repr)
+    subs = independent.split(hist, ks)
+    assert hist.walks <= 2
+    assert len(subs) == n_keys
+    assert all(len(sub) == 3 for sub in subs.values())
+
+
+def test_per_key_parts_none_when_unkeyed():
+    from jepsen_tpu.analysis import searchplan
+    hist = [inv(0, "w", 1), ok(0, "w", 1), _nem("kill"),
+            inv(1, "w", ["a", 2]), ok(1, "w", ["a", 2])]
+    assert searchplan.per_key_parts(hist) is None
+    keyed = hist + [inv(2, "w", T("b", 3)), ok(2, "w", T("b", 3))]
+    parts = searchplan.per_key_parts(keyed)
+    assert list(parts) == ["b"]
+    assert parts["b"] == independent.subhistory("b", keyed)
+
+
 def test_concurrent_generator_groups_and_rotation():
     """2 threads per key over 4 worker threads: two keys in flight;
     exhausted groups rotate to fresh keys (independent.clj:103-236)."""

@@ -419,14 +419,11 @@ def phase_mesh(devs):
     """--chips 4: phase b over a keys mesh and phase c's valid history
     through the sharded single-key search, each against the one-device
     engine in this process."""
-    import numpy as np
-    from jax.sharding import Mesh
-
     from jepsen_tpu.checker import jax_wgl
     from jepsen_tpu.models import cas_register_spec as spec
     from jepsen_tpu.parallel import check_batch_encoded, \
-        check_encoded_sharded
-    mesh = Mesh(np.array(devs), ("keys",))
+        check_encoded_sharded, keys_mesh
+    mesh = keys_mesh(len(devs))
     pairs = [spec.encode(h) for h in batch_histories()]
     # the valid long history: its corrupted twin never reaches the
     # search (the host pre-check refutes it), so it has no shards

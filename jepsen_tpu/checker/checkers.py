@@ -95,6 +95,19 @@ class Linearizable(Checker):
         self.spec = mbase.model_spec(model)
         self.algorithm = algorithm
         self.engine_opts = engine_opts or {}
+        if "chips" in self.engine_opts:
+            # the chip count becomes the 1-D keys mesh the device
+            # engines take; an explicit mesh wins, and one chip is the
+            # one-device path
+            opts = dict(self.engine_opts)
+            chips = opts.pop("chips")
+            if not isinstance(chips, int) or chips < 1:
+                raise ValueError(f"engine_opts chips must be a whole "
+                                 f"number >= 1, not {chips!r}")
+            if chips > 1 and "mesh" not in opts:
+                from ..parallel import keys_mesh
+                opts["mesh"] = keys_mesh(chips)
+            self.engine_opts = opts
         #: ops establishing the initial state, e.g. [{"f": "write",
         #: "value": 0}] for a register pre-set to 0 (the reference's
         #: (model/cas-register 0)). Prepended as already-completed pairs

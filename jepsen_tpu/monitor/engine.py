@@ -79,7 +79,8 @@ def check_prefix(spec, e, init_state, engine="jax-wgl",
         opts = dict(engine_opts or {})
         # the mesh/checkpoint machinery is offline-only; a monitor
         # check is short-lived and re-runs every chunk
-        for k in ("mesh", "checkpoint", "checkpoint_every_s", "confirm"):
+        for k in ("chips", "mesh", "checkpoint", "checkpoint_every_s",
+                  "confirm"):
             opts.pop(k, None)
         return jax_wgl.check_encoded(spec, e, init_state, cancel=cancel,
                                      **opts)

@@ -22,7 +22,10 @@ search (parallel/searchshard.py) — one cheap call per dispatch:
   explored/frontier series mid-search;
 * `summary()` records the final verdict's telemetry (states explored,
   chunk count, iteration count, dedup-table load / insert failures,
-  per-shard work split) into the metrics registry.
+  per-shard work split: ``wgl.shard_explored_total{shard}``, and
+  ``shard_explored`` / ``shard_max_share`` on the ``wgl.done`` event)
+  into the metrics registry and the trace. The mesh-sharded search and
+  the key batch under a mesh pass the split.
 
 Engines call `capture()` ONCE at search entry and use the returned
 session for every emission. The session pins the sinks resolved
@@ -262,6 +265,9 @@ class SearchObs:
                 reg.inc("wgl.table_insert_failures",
                         int(result["table_insert_failures"]),
                         engine=engine)
+            for i, n in enumerate(shard_explored or ()):
+                reg.inc("wgl.shard_explored_total", int(n),
+                        engine=engine, shard=str(i))
         if tr is not None:
             fields = {k: result.get(k) for k in
                       ("valid", "configs_explored", "iterations",

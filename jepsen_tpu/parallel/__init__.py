@@ -8,6 +8,7 @@ dimension sharded over a ``jax.sharding.Mesh`` (SURVEY.md section 5
 "Distributed communication backend").
 """
 
-from .keyshard import check_batch_encoded, check_batch_histories  # noqa: F401
+from .keyshard import (check_batch_encoded,  # noqa: F401
+                       check_batch_histories, keys_mesh)
 from .searchshard import (check_encoded_sharded,  # noqa: F401
                           check_history_sharded)

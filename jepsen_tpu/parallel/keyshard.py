@@ -22,6 +22,8 @@ the per-key frontier as the batch shrinks to keep the chip busy.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import time as _time
 
@@ -37,6 +39,7 @@ from ..checker.jax_wgl import (IDX_BEST_DEPTH, IDX_BEST_LIN,
                                N_CARRY, RUNNING, _bucket, _build_search,
                                _encode_arrays, _plan_sizes,
                                max_point_concurrency, table_stats)
+from .. import obs
 from ..history import INF_TIME
 from ..obs import phases as obs_phases
 from ..obs import search as obs_search
@@ -91,12 +94,48 @@ def _dummy_key(n_pad, S_pad, A):
             None)
 
 
+def keys_mesh(chips):
+    """The 1-D ``keys`` mesh over the first ``chips`` devices: the layout
+    ``engine_opts={"chips": n}`` asks the key batch to shard over."""
+    from jax.sharding import Mesh
+    devs = jax.devices()
+    if not 1 <= chips <= len(devs):
+        raise ValueError(f"keys_mesh: {chips} chips asked for, JAX has "
+                         f"{len(devs)} devices")
+    return Mesh(np.array(devs[:chips]), ("keys",))
+
+
+def mesh_axis(mesh):
+    """The one axis of a 1-D mesh; both mesh paths shard over it."""
+    if len(mesh.axis_names) != 1:
+        raise ValueError(f"a key or search mesh must be 1-D; this one "
+                         f"has axes {tuple(mesh.axis_names)}")
+    return mesh.axis_names[0]
+
+
 def _shard_specs(mesh, n_carry=N_CARRY, n_consts=8):
     from jax.sharding import PartitionSpec as P
-    ax = mesh.axis_names[0]
+    ax = mesh_axis(mesh)
     carry_specs = tuple(P(ax) for _ in range(n_carry))
     const_specs = tuple(P(ax) for _ in range(n_consts - 1)) + (P(),)
     return carry_specs, const_specs
+
+
+@functools.lru_cache(maxsize=64)
+def _mesh_runner(step, k_local, n_pad, B, S_pad, C, A, W, O, T, R, NS,
+                 mesh):
+    """run_chunk of the key batch under ``shard_map`` over ``mesh``, built
+    once per shape: a fresh ``jax.jit`` per check or per compaction would
+    compile again every time. The kernel sees LOCAL shapes: ``k_local``
+    keys and one table group per device."""
+    carry_specs, const_specs = _shard_specs(mesh)
+    run_local = _build_search(step, k_local, n_pad, B, S_pad, C, A, W, O,
+                              T, 1, R=R, NS=NS,
+                              rollout_kernel="scan").run_chunk
+    return jax.jit(shard_map_compat(
+        run_local.__wrapped__, mesh,
+        (carry_specs,) + const_specs, carry_specs),
+        donate_argnums=(0,))
 
 
 def check_batch_encoded(spec, pairs, max_configs=50_000_000,
@@ -228,7 +267,7 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
     K = _bucket(len(cols), 1)
     G = 1
     if mesh is not None:
-        G = int(mesh.shape[mesh.axis_names[0]])
+        G = int(mesh.shape[mesh_axis(mesh)])
         while K % G:
             K += 1
     while len(cols) < K:
@@ -250,7 +289,7 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
 
     def _keyed_sharding():
         from jax.sharding import NamedSharding, PartitionSpec as P
-        return NamedSharding(mesh, P(mesh.axis_names[0]))
+        return NamedSharding(mesh, P(mesh_axis(mesh)))
 
     def build_runner(Kc, Wc):
         """run_chunk for a (possibly compacted/resumed) batch width."""
@@ -262,17 +301,8 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
                                  Wc, O, T, G, R=R_batch,
                                  NS=rollout_seeds,
                                  rollout_kernel="scan").run_chunk
-        carry_specs, const_specs = _shard_specs(mesh)
-        # the kernel run under shard_map sees LOCAL shapes: Kc/G keys
-        # and one table group per device
-        run_local = _build_search(spec.step, Kc // G, n_pad, B,
-                                  S_pad, C, A, Wc, O, T, 1,
-                                  R=R_batch, NS=rollout_seeds,
-                                  rollout_kernel="scan").run_chunk
-        return jax.jit(shard_map_compat(
-            run_local.__wrapped__, mesh,
-            (carry_specs,) + const_specs, carry_specs),
-            donate_argnums=(0,))
+        return _mesh_runner(spec.step, Kc // G, n_pad, B, S_pad, C, A,
+                            Wc, O, T, R_batch, rollout_seeds, mesh)
 
     def wide_W(Kc):
         # budget lanes per DEVICE (each shard runs Kc // G keys),
@@ -341,6 +371,15 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
     last_ckpt = t0
     timed_out = False
     n_compactions = 0
+    # per-chip work under a mesh, from the explored counters each poll
+    # already fetches: each live key's growth since the last poll goes
+    # to the chip that holds its row (work done before a checkpoint
+    # resume goes to the chip the key resumes on; keys harvested before
+    # the resume are not credited)
+    shard_explored = credited = None
+    if mesh is not None:
+        shard_explored = np.zeros(G, np.int64)
+        credited = np.zeros(len(live), np.int64)
     # sinks captured once at search start (see obs.search docstring)
     so = obs_search.capture()
     # padding accounting: the batch pads every live key to the common
@@ -433,6 +472,18 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
         # the gauge stays monotone across compactions
         explored_k = np.asarray(explored_k)
         bdepth = np.asarray(bdepth)
+        shard_tops = None
+        if mesh is not None:
+            # credited here, before a compaction below moves any row
+            rows_per_chip = len(alive) // G
+            alive_np = np.asarray(alive)
+            rows = np.flatnonzero(alive_np >= 0)
+            keys_at = alive_np[rows]
+            grown = explored_k[rows].astype(np.int64) - credited[keys_at]
+            np.add.at(shard_explored, rows // rows_per_chip, grown)
+            credited[keys_at] += grown
+            shard_tops = np.bincount(
+                np.flatnonzero(running) // rows_per_chip, minlength=G)
         so.heartbeat(
             "jax-wgl-batch", iteration=it,
             chunk_s=_time.monotonic() - t_chunk,
@@ -443,7 +494,7 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
             + sum(int(h["explored"]) for h in harvested.values()),
             depth=max(0, int(bdepth.max())),
             keys_alive=len(alive), keys_running=n_run,
-            compactions=n_compactions)
+            compactions=n_compactions, shard_tops=shard_tops)
         if n_run == 0:
             harvest(range(len(alive)), carry)
             break
@@ -473,28 +524,36 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
                 newK += 1              # device under a mesh
             pad_row = done_rows[0]
             idx = keep + [pad_row] * (newK - n_run)
-            sel = jnp.asarray(np.asarray(idx, np.int32))
-            carry = tuple(jnp.take(c, sel, axis=0) if i in KEYED else c
-                          for i, c in enumerate(carry))
-            consts = tuple(jnp.take(c, sel, axis=0) for c in consts)
-            alive = [alive[r] for r in keep] + [-1] * (newK - n_run)
-            # widen per-key frontiers as the batch shrinks; under a
-            # mesh, keys reshard and a moved key misses its old
-            # device's dedup entries (key-salted, so only a perf cost,
-            # never a correctness one)
-            run_b = build_runner(newK, wide_W(newK))
+            span = contextlib.nullcontext()
             if mesh is not None:
-                carry = tuple(jax.device_put(x, _keyed_sharding())
-                              if i in KEYED else x
-                              for i, x in enumerate(carry))
-                consts = tuple(jax.device_put(x, _keyed_sharding())
-                               for x in consts)
+                # a key moves when its chip before and after differ
+                moved = sum(r // (len(alive) // G) != i // (newK // G)
+                            for i, r in enumerate(keep))
+                span = obs.span("keyshard.compact", cat="search", chips=G,
+                                keys_before=len(alive), keys_after=newK,
+                                moved=moved)
+            with span:
+                sel = jnp.asarray(np.asarray(idx, np.int32))
+                carry = tuple(jnp.take(c, sel, axis=0) if i in KEYED
+                              else c for i, c in enumerate(carry))
+                consts = tuple(jnp.take(c, sel, axis=0) for c in consts)
+                alive = [alive[r] for r in keep] + [-1] * (newK - n_run)
+                # widen per-key frontiers as the batch shrinks; under a
+                # mesh, keys reshard and a moved key misses its old
+                # device's dedup entries (key-salted, so only a perf
+                # cost, never a correctness one)
+                run_b = build_runner(newK, wide_W(newK))
+                if mesh is not None:
+                    carry = tuple(jax.device_put(x, _keyed_sharding())
+                                  if i in KEYED else x
+                                  for i, x in enumerate(carry))
+                    consts = tuple(jax.device_put(x, _keyed_sharding())
+                                   for x in consts)
 
     # never clobber a snapshot that belongs to a DIFFERENT check: the
     # path may have been (re)claimed by a concurrent run since startup
     if checkpoint is not None and jax_wgl._checkpoint_owned(checkpoint,
                                                             fingerprint):
-        import contextlib
         import os
         all_decided = (not timed_out and len(harvested) == len(live)
                        and all(int(h["status"]) != RUNNING
@@ -530,6 +589,9 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
         results[k]["compactions"] = n_compactions
         if n_owners is not None:
             results[k]["batch_owners"] = n_owners
+        if shard_explored is not None:
+            # batch-wide, like the table stats: the search each chip ran
+            results[k]["shard_explored"] = shard_explored.tolist()
     if so.enabled():
         so.summary(
             "jax-wgl-batch",
@@ -540,7 +602,9 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
                  (int(h["iterations"]) for h in harvested.values()),
                  default=0),
              **tstats},
-            keys=len(live))
+            keys=len(live),
+            shard_explored=None if shard_explored is None
+            else shard_explored.tolist())
     ph.lap("host")
     return results
 

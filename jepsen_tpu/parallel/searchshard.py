@@ -59,7 +59,7 @@ from ..checker.jax_wgl import (IDX_BEST_DEPTH, IDX_BEST_LIN,
                                RUNNING, VALID, _build_search, _plan_sizes)
 from ..obs import phases as obs_phases
 from ..obs import search as obs_search
-from .keyshard import _shard_specs, shard_map_compat
+from .keyshard import _shard_specs, mesh_axis, shard_map_compat
 
 logger = logging.getLogger(__name__)
 
@@ -74,7 +74,8 @@ def check_encoded_sharded(spec, e, init_state, mesh,
     """Run ONE search for ``e`` sharded over ``mesh`` (1-D). Result
     dict matches jax_wgl.check_encoded, plus per-shard diagnostics
     (``shard_explored``) proving the steal ring spread the work."""
-    D = int(mesh.shape[mesh.axis_names[0]])
+    ax = mesh_axis(mesh)
+    D = int(mesh.shape[ax])
     # phase cursor (obs.phases): per-dispatch encode/plan/h2d/compile/
     # device/d2h/host attribution for the mesh loop
     ph = obs_phases.capture("jax-wgl-sharded")
@@ -96,7 +97,6 @@ def check_encoded_sharded(spec, e, init_state, mesh,
 
     # the local kernel: ONE shard of the search (K=1, its own table
     # group), with the steal ring + global-termination collectives
-    ax = mesh.axis_names[0]
     run_local = _build_search(spec.step, 1, n_pad, B, S, C, A, W, O,
                               T, 1, NS=rollout_seeds,
                               rollout_kernel="scan", axis_name=ax,

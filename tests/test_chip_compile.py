@@ -121,6 +121,42 @@ def test_batch_search_chunk_k256_compiles_for_v5e(one_chip):
              ((K, B), jnp.uint32), ((K,), jnp.uint32))
 
 
+def test_keyed_mesh_chunk_compiles_for_v5e_2x2(topo):
+    """One chunk of the key batch under ``shard_map`` over a ``keys``
+    mesh of the four chips of a v5e host, at the per-key widths of the
+    4096-key register cell (n=64, C=16, W=32, O=32768, T=2**20) with 8
+    keys a chip: the chips share no collective in the hot loop."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from jepsen_tpu.parallel import keyshard
+    k_local, n, B, C, W, O, T = 8, 64, 2, 16, 32, 32768, 1 << 20
+    mesh = Mesh(np.array(topo.devices[:4]), ("keys",))
+    G = mesh.devices.size
+    K = k_local * G
+    keyed = NamedSharding(mesh, P("keys"))
+    full = jax_wgl._build_search(spec.step, K, n, B, S, C, A, W, O, T, G,
+                                 R=0, NS=1, rollout_kernel="scan")
+    carry = jax.eval_shape(full.init_carry,
+                           jax.ShapeDtypeStruct((K, S), jnp.int32))
+    args = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=keyed)
+            for c in carry]
+    args += [jax.ShapeDtypeStruct(shape, dtype, sharding=keyed)
+             for shape, dtype in [((K, n), jnp.int32)] * 3
+             + [((K, n, A), jnp.int32)] * 2
+             + [((K, B), jnp.uint32), ((K,), jnp.uint32)]]
+    bound = jax.ShapeDtypeStruct((), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    run = keyshard._mesh_runner(spec.step, k_local, n, B, S, C, A, W, O,
+                                T, 0, 1, mesh)
+    with _no_persistent_cache():
+        text = run.lower(tuple(args[:len(carry)]), *args[len(carry):],
+                         bound).compile().as_text()
+    for op in ("all-reduce", "all-gather", "collective-permute",
+               "all-to-all", "reduce-scatter"):
+        assert op not in text
+
+
 def test_streamlin_solo_fold_compiles_for_v5e(one_chip):
     """The monitor's fold at its starting frontier/window shape."""
     F, NW = streamlin.FRONTIER_FLOOR, streamlin.WINDOW_FLOOR

@@ -9,7 +9,11 @@ device trace:
 * certification's replays are named (``certify.*``) while the replayed
   engines' own counters stay silent;
 * ``obs.profile.anchor`` maps the tracer's spans onto the profiler's
-  clock to well under a millisecond.
+  clock to well under a millisecond;
+* a keyed batch over a chip mesh names its compactions
+  (``keyshard.compact``) and reports each chip's share of the search
+  (``shard_explored`` on ``wgl.done``, ``wgl.shard_explored_total``);
+  a one-device batch reports neither.
 """
 
 import glob
@@ -251,3 +255,41 @@ def test_profile_scope_opens_the_anchor(tmp_path):
     assert len(anchors) == 1
     inner = [e for e in _spans(tr) if e["name"] == "analyze"]
     assert inner[0]["args"]["parent"] == obs_profile.ANCHOR
+
+
+# ---------------------------------------------------------------------------
+# the keyed batch over a chip mesh
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_keyed_mesh_batch_spans_and_chip_counters(chips):
+    import jax
+    from test_keyshard_chips import check, keyed_cas_history, keyed_checker
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    tr, reg = obs.Tracer(), obs.Registry()
+    with obs.sink_scope(tr, reg):
+        res = check(keyed_checker(chips=chips), keyed_cas_history()[1])
+    assert any(r.get("compactions") for r in res["results"].values())
+    compacts = [e for e in _spans(tr, "search")
+                if e["name"] == "keyshard.compact"]
+    done = [e["args"] for e in tr.events()
+            if e["name"] == "wgl.done.jax-wgl-batch"]
+    per_chip = {dict(labels)["shard"]: v for (name, labels), v
+                in reg.series()["counters"].items()
+                if name == "wgl.shard_explored_total"}
+    assert len(done) == 1
+    if chips == 1:
+        assert compacts == [] and per_chip == {}
+        assert "shard_explored" not in done[0]
+        return
+    for e in compacts:
+        a = e["args"]
+        assert a["chips"] == 4
+        assert a["keys_before"] > a["keys_after"] >= 4
+        assert 0 <= a["moved"] <= a["keys_after"]
+    assert compacts and any(e["args"]["moved"] for e in compacts)
+    split = done[0]["shard_explored"]
+    assert len(split) == 4 and sum(split) == done[0]["configs_explored"]
+    assert done[0]["shard_max_share"] == round(max(split) / sum(split), 4)
+    assert per_chip == {str(i): n for i, n in enumerate(split)}

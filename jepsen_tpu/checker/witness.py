@@ -10,7 +10,8 @@ state), so this module reconstructs the rest on host:
 
 * ``final_path`` replays the linearized SET into a legal WGL ORDER
   (depth-first over set members under the real eligibility rule, guided
-  by the model's linearization-priority hint) and records the model
+  by the model's linearization-priority hint, remembering the (placed
+  set, model state) pairs it has seen fail) and records the model
   state after every step.
 * ``attach`` shapes the knossos-style fields onto a result dict:
   ``final_paths`` (list of paths of ``{"op", "model"}``),
@@ -24,8 +25,11 @@ code, so their failure artifacts are interchangeable.
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
+from .. import obs
 from ..history import INF_TIME
 
 #: keep at most this many trailing steps per reported path (a 10k-op
@@ -37,6 +41,20 @@ PATH_TAIL = 100
 #: a real search path so the hint-guided replay almost never backtracks,
 #: but an adversarial set could force exponential work
 REPLAY_BUDGET = 500_000
+
+#: bytes the replay DFS may spend remembering dead (placed set, model
+#: state) pairs: a 10k-member mask is ~1.3 KB, and up to REPLAY_BUDGET
+#: dead pairs could form. Past the cap no entry is added; the replay
+#: stays exact and only prunes less
+REPLAY_MEMO_BYTES = 64 << 20
+
+#: bytes one memo entry costs beyond its mask and state: the key tuple,
+#: the int and bytes object headers and the set slot
+REPLAY_MEMO_ENTRY_OVERHEAD = 160
+
+#: replays with more members than this run inside a ``witness.replay``
+#: span; the many short keys of a keyed batch get none
+REPLAY_SPAN_MEMBERS = 32
 
 #: version tag of the NORMALIZED witness dict (``result["witness"]``)
 #: every engine emits -- the one schema the verdict certifier
@@ -79,12 +97,44 @@ def final_path(spec, e, linearized, init_state, budget=REPLAY_BUDGET):
 
     ``linearized``: bool[n] over ``e``'s rows. Returns a list of
     ``(row_index, state_after)`` or None if the replay budget runs out
-    (the witness then stays set-only)."""
-    n = len(e)
+    (the witness then stays set-only). Counts each call in
+    ``witness.replays{outcome}``, ``witness.replay_attempts`` and
+    ``witness.replay_pruned``; a replay of more than
+    ``REPLAY_SPAN_MEMBERS`` members runs inside a ``witness.replay``
+    span."""
     member = np.asarray(linearized, bool)
     total = int(member.sum())
+    scope = (obs.span("witness.replay", cat="witness", members=total)
+             if total > REPLAY_SPAN_MEMBERS else contextlib.nullcontext())
+    with scope as sp:
+        path, stats = _replay(spec, e, member, init_state, budget)
+        if sp is not None:
+            sp["ordered"] = path is not None
+    obs.inc("witness.replays", outcome=stats["outcome"])
+    obs.inc("witness.replay_attempts", stats["attempts"])
+    obs.inc("witness.replay_pruned", stats["pruned"])
+    return path
+
+
+def _replay(spec, e, member, init_state, budget):
+    """``final_path``'s DFS. Returns ``(path or None, stats)``; stats:
+    ``outcome`` (ordered | budget | exhausted, the DFS backing out of
+    its root), ``attempts`` (step attempts spent), ``pruned`` (children
+    skipped as dead), ``memo_entries`` and ``memo_bytes``.
+
+    A DFS node's future depends only on the members still to place and
+    the model state: the eligibility minimum is a function of the placed
+    set, and each level scans the unplaced members in the same order.
+    So a node popped with every child tried is dead wherever it recurs,
+    and skipping a child whose (placed set, state) is dead drops only
+    subtrees that cannot complete. The first complete order in DFS
+    order -- the one the unmemoised DFS returns -- is unchanged, and it
+    is reached in no more step attempts."""
+    total = int(member.sum())
     if total == 0:
-        return []
+        return [], {"outcome": "ordered", "attempts": 0, "pruned": 0,
+                    "memo_entries": 0, "memo_bytes": 0}
+    n = len(e)
     invoke = np.asarray(e.invoke_idx, np.int64)
     rets = np.asarray(e.return_idx, np.int64)
     f = np.asarray(e.f)
@@ -123,23 +173,42 @@ def final_path(spec, e, linearized, init_state, budget=REPLAY_BUDGET):
     states = [np.asarray(init_state, np.int32)]
     scan = [nxt[head]]        # per-level next list position to try
     work = budget
+
+    # dead (placed-position bitmask, state bytes) pairs, kept within
+    # REPLAY_MEMO_BYTES; past it the DFS only prunes less
+    dead = set()
+    entry_bytes = ((total + 7) // 8 + states[0].nbytes
+                   + REPLAY_MEMO_ENTRY_OVERHEAD)
+    memo_bytes = pruned = 0
+    mask = 0
+
+    def stats(outcome):
+        return {"outcome": outcome, "attempts": min(budget, budget - work),
+                "pruned": pruned, "memo_entries": len(dead),
+                "memo_bytes": memo_bytes}
+
     while True:
         if len(path) == total:
-            return path
+            return path, stats("ordered")
         j = scan[-1]
         state = states[-1]
         taken = False
         while j != head:
             work -= 1
             if work < 0:
-                return None
+                return None, stats("budget")
             i = members[j]
             if invoke[i] < tree.min():
                 st2, ok = spec.step(state, f[i], args[i], rvals[i], np)
                 if bool(ok):
                     st2 = np.asarray(st2, np.int32)
+                    if dead and (mask | (1 << j), st2.tobytes()) in dead:
+                        pruned += 1
+                        j = nxt[j]
+                        continue
                     tree.set_active(i, False)
                     remove(j)
+                    mask |= 1 << j
                     path.append((i, st2))
                     states.append(st2)
                     scan[-1] = j          # resume point on backtrack
@@ -151,10 +220,14 @@ def final_path(spec, e, linearized, init_state, budget=REPLAY_BUDGET):
             scan.pop()
             states.pop()
             if not path:
-                return None
+                return None, stats("exhausted")
+            if memo_bytes + entry_bytes <= REPLAY_MEMO_BYTES:
+                dead.add((mask, state.tobytes()))
+                memo_bytes += entry_bytes
             i, _ = path.pop()
             jprev = scan[-1]
             restore(jprev)
+            mask ^= 1 << jprev
             tree.set_active(i, True)
             scan[-1] = nxt[jprev]
 

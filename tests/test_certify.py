@@ -262,6 +262,27 @@ def test_vc009_budget_exhausted_is_info_not_fatal():
     assert not [d for d in diags if d.severity == "error"]
 
 
+def test_contended_invalid_key_witness_replays():
+    """A contended invalid cas-register key (200 ops over 10
+    processes, one read flipped): the witness replay orders the
+    deepest configuration, so certification replays the proof instead
+    of recording it unreplayed (VC009)."""
+    from test_witness_replay import contended_history
+    spec = mbase.model_spec("cas-register")
+    hist = h.ensure_indexed(contended_history())
+    lin = Linearizable(spec, algorithm="linear")
+    r = lin.check({}, hist, {})
+    assert r["valid"] is False
+    client = lin.prepare_history(h.client_ops(hist))
+    cert, diags = certify.certify_with_diagnostics(spec, client, r,
+                                                   samples=0)
+    status = [c["status"] for c in cert["checks"]
+              if c["name"].startswith("witness")]
+    assert status and "unreplayed" not in status
+    assert "VC009" not in _codes(diags)
+    assert not [d for d in diags if d.severity == "error"]
+
+
 def test_vc010_differential_divergence(monkeypatch):
     """A lying engine in the differential table is caught."""
     monkeypatch.setitem(certify.DIFF_ENGINES, "wgl",

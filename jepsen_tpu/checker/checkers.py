@@ -156,8 +156,8 @@ class Linearizable(Checker):
             elif algo == "linear":
                 a = linear.check_encoded(self.spec, e, init_state)
             elif algo == "jax-wgl":
-                opts = dict(self.engine_opts)
-                mesh = opts.pop("mesh", None)
+                eopts = dict(self.engine_opts)
+                mesh = eopts.pop("mesh", None)
                 if mesh is not None:
                     # one SINGLE-key search sharded across the mesh
                     # (parallel/searchshard.py); the multi-key batched
@@ -169,17 +169,17 @@ class Linearizable(Checker):
                     keep = {"max_configs", "frontier_width",
                             "stack_size", "table_size", "timeout_s",
                             "chunk_iters", "steal", "rollout_seeds"}
-                    dropped = sorted(set(opts) - keep)
+                    dropped = sorted(set(eopts) - keep)
                     if dropped:
                         logger.warning(
                             "engine_opts %s are not supported by the "
                             "mesh-sharded search; ignoring", dropped)
                     a = check_encoded_sharded(
                         self.spec, e, init_state, mesh,
-                        **{k: v for k, v in opts.items() if k in keep})
+                        **{k: v for k, v in eopts.items() if k in keep})
                 else:
                     a = jax_wgl.check_encoded(self.spec, e, init_state,
-                                              **opts)
+                                              **eopts)
             else:
                 a = self._competition(e, init_state)
         # truncate heavyweight fields (checker.clj:213-216: "writing

@@ -59,7 +59,6 @@ from jax import lax
 
 from ..history import INF_TIME
 from ..obs import phases as obs_phases
-from ..obs import search as obs_search
 
 INF32 = np.int32(2**31 - 1)
 
@@ -216,7 +215,7 @@ def _build_search(step_fn, K, n, B, S, C, A, W, O, T, G=1, R=None,
         # branch-and-bound; only trivially short histories skip it.
         # (Round 2 used a 256-op cutoff, which left the multi-key batch
         # -- 200-op histories per key -- grinding one depth level per
-        # iteration; lowering it to 64 cut rung 2 device time ~3x.)
+        # iteration.)
         # SINGLE-KEY searches run deep chains (R=1024): a deep rollout
         # amortizes the expensive expansion/dedup iteration over 4x
         # the depth, and the win holds on BOTH rollout kernels (A/B,
@@ -926,14 +925,14 @@ def _note_compile(engine, key):
 
 
 def _adapt_quantum(cap, per_it, target_s, left_s=None):
-    """Next dispatch quantum (shared by the single-key and batched
-    loops): ~``target_s`` of measured per-iteration wall, capped by the
-    caller's ``chunk_iters`` contract, and shrunk to fit the remaining
-    wall budget ``left_s`` (budgets are only enforced BETWEEN
-    dispatches, so a mispredicted quantum is the whole overshoot).
-    Both fixed policies failed measurably: large chunks overshot a
-    60 s budget to 282 s; fixed-small chunks made big searches
-    bound by host round trips (PROFILE.md round 4)."""
+    """Next dispatch quantum of the search's host loop
+    (``checker/drive.py``): ~``target_s`` of measured per-iteration
+    wall, capped by the caller's ``chunk_iters`` contract, and shrunk to
+    fit the remaining wall budget ``left_s`` (budgets are only enforced
+    BETWEEN dispatches, so a mispredicted quantum is the whole
+    overshoot). Both fixed policies failed measurably: large chunks
+    overshot a 60 s budget to 282 s; fixed-small chunks made big
+    searches bound by host round trips (PROFILE.md round 4)."""
     eff = max(1, min(cap, int(target_s / per_it)))
     if left_s is not None:
         eff = max(1, min(eff, int(left_s / per_it) + 1))
@@ -1191,6 +1190,7 @@ def check_encoded(spec, e, init_state, max_configs=50_000_000,
     resumes from the snapshot instead of restarting; snapshots carry a
     fingerprint of the search inputs so a stale file for a different
     history or plan is ignored."""
+    from . import drive
     # phase cursor (obs.phases): attributes this search's wall to
     # encode/plan/h2d/compile/device/d2h/host spans; a pair of clock
     # reads per lap when obs is unbound
@@ -1218,222 +1218,34 @@ def check_encoded(spec, e, init_state, max_configs=50_000_000,
         spec.step, 1, n_pad, B, S, C, A, W, O, T, R=rollout_depth,
         NS=rollout_seeds, rollout_kernel=rollout_kernel)
     ph.lap("plan")
-    consts = (jnp.asarray(inv32[None]), jnp.asarray(ret32[None]),
-              jnp.asarray(fop[None]), jnp.asarray(args[None]),
-              jnp.asarray(rets[None]), jnp.asarray(ok_words[None]),
-              jnp.zeros(1, jnp.uint32))
+    # the key batch's row model with one row: key 0, salt 0
+    col = (inv32, ret32, fop, args, rets, ok_words, init_state)
+    consts = tuple(jnp.asarray(x[None]) for x in col[:6]) + (
+        jnp.zeros(1, jnp.uint32),)
     carry = init_carry(jnp.asarray(init_state[None]))
-    ph.sync(carry)
-    ph.lap("h2d")
-    import time as _time
-    fingerprint = None
-    if checkpoint is not None:
-        import hashlib
-        h = hashlib.sha256()
-        h.update(CARRY_LAYOUT.encode())
-        h.update(spec.name.encode())
-        for a in (inv32, ret32, fop, args, rets, ok_words, init_state,
-                  np.asarray([n_pad, B, S, C, W, O, T], np.int64)):
-            h.update(np.ascontiguousarray(a).tobytes())
-        fingerprint = h.hexdigest()
-        resumed = _load_checkpoint(checkpoint, fingerprint)
-        if resumed is not None:
-            carry = tuple(jnp.asarray(x) for x in resumed)
-        elif not _checkpoint_owned(checkpoint, fingerprint):
-            # the path holds a different check's live snapshot; don't
-            # touch it (all later saves/cleanup honor this too)
-            import logging
-            logging.getLogger(__name__).warning(
-                "checkpoint %s belongs to a different check; "
-                "checkpointing disabled for this run", checkpoint)
-            checkpoint = None
-    t0 = _time.monotonic()
-    last_ckpt = t0
-    timed_out = False
-    # sinks captured ONCE at search start: a competition-abandoned
-    # straggler must not write into a later run's artifacts
-    so = obs_search.capture()
-    # padding accounting: one real history of len(e) rows rides an
-    # n_pad-row padded plan (power-of-two bucket for compile reuse);
-    # the per-bucket real/padded counters feed the waste table
-    so.plan("jax-wgl", n_pad, len(e), n_pad)
-    it = int(carry[IDX_IT][0])
-    # Adaptive dispatch quantum. ``chunk_iters`` is the CAP (explicit
-    # tiny values are a cadence contract the checkpoint tests rely
-    # on); within it, the quantum is chosen from the measured
-    # per-iteration wall so each dispatch targets ~3 s and never
-    # overshoots the remaining budget by more than ~one misprediction.
-    # Both failure modes are measured: a fixed 32-iteration chunk
-    # overshot a 60 s budget to 282 s on a 96k-op history (budgets are
-    # only enforced BETWEEN dispatches), and a fixed-small chunk
-    # made the same history SYNC-bound -- hundreds of host round
-    # trips (PROFILE.md).
-    # first dispatch: small enough to calibrate cheaply even at huge
-    # shapes (a 32-iteration first chunk at n_pad=262k ran 353 s
-    # before the first budget check); adaptation takes over after it
-    eff = min(chunk_iters, 32, max(1, (32 * 16384) // n_pad))
-    while True:
-        prev_it = it
-        t_chunk = _time.monotonic()
-        bound = min(it + eff, max_iters)
-        ph.lap("host")
-        carry = run_chunk(carry, *consts, jnp.int32(bound))
-        # device-compute bracket: the sync exists ONLY while phase
-        # attribution is on (otherwise the progress device_get below
-        # stays the dispatch's one sync, as before)
-        ph.sync(carry)
-        dev_s = ph.lap("device", iteration=it)
-        # ONE host round-trip for the whole progress tensor (each
-        # separate device_get is a sync; see table_stats): status/top/it/explored scalars plus the TOPK
-        # witness depths, whose max is the deepest linearized-ok count
-        # reached — the search's progress toward n_ok
-        status, top, it, explored, bdepth = jax.device_get(
-            (carry[IDX_STATUS][0], carry[IDX_TOP][0],
-             carry[IDX_IT][0], carry[IDX_EXPLORED][0],
-             carry[IDX_BEST_DEPTH][0]))
-        status, top, it, explored = (int(status), int(top), int(it),
-                                     int(explored))
-        ph.lap("d2h")
-        # heartbeat per dispatch: long searches stop being a silent jit
-        # black box (frontier depth + cumulative explored + deepest op
-        # reached, streamed to the captured tracer/registry; no-op when
-        # obs is unbound, and no extra device round-trips either way —
-        # everything rides the batched device_get above)
-        so.heartbeat(
-            "jax-wgl", iteration=it,
-            chunk_s=_time.monotonic() - t_chunk,
-            device_s=dev_s if ph.enabled else None, frontier=top,
-            explored=explored,
-            depth=max(0, int(np.asarray(bdepth).max())))
-        if status != RUNNING or top == 0 or it >= max_iters:
-            break
-        now = _time.monotonic()
-        per_it = max(1e-4, (now - t_chunk) / max(1, it - prev_it))
-        eff = _adapt_quantum(
-            chunk_iters, per_it, 3.0,
-            timeout_s - (now - t0) if timeout_s is not None else None)
-        if checkpoint is not None and \
-                now - last_ckpt >= checkpoint_every_s:
-            _save_checkpoint(checkpoint, fingerprint, carry)
-            last_ckpt = now
-        if (timeout_s is not None and now - t0 > timeout_s) or \
-                (cancel is not None and cancel.is_set()):
-            timed_out = True
-            if checkpoint is not None:
-                _save_checkpoint(checkpoint, fingerprint, carry)
-            break
-
-    ph.lap("host")
-    out = {"status": carry[IDX_STATUS][0], "top": carry[IDX_TOP][0],
-           "dropped": carry[IDX_DROPPED][0],
-           "explored": carry[IDX_EXPLORED][0],
-           "iterations": carry[IDX_ITS][0],
-           "best_depth": carry[IDX_BEST_DEPTH][0],
-           "best_lin": carry[IDX_BEST_LIN][0],
-           "best_state": carry[IDX_BEST_STATE][0]}
-    out = jax.device_get(out)
-    tstats = table_stats(carry)
-    ph.lap("d2h")
-    if timed_out and int(out["status"]) == RUNNING and int(out["top"]) > 0:
-        result = {"valid": "unknown", "error": "timeout",
-                  "configs_explored": int(out["explored"]),
-                  "iterations": int(out["iterations"]),
-                  "engine": "jax-wgl", "rollout": rollout, **tstats,
-                  **({"checkpoint": checkpoint} if checkpoint else {})}
-        so.summary("jax-wgl", result)
-        ph.lap("host")
-        return result
-    result = _interpret(spec, e, out, max_iters, confirm, init_state,
-                        perm)
+    result, = drive.drive(
+        ph, "jax-wgl", spec, [(e, init_state, perm)], carry, consts,
+        run_chunk, max_iters=max_iters, chunk_iters=chunk_iters,
+        timeout_s=timeout_s, cancel=cancel, confirm=confirm,
+        checkpoint=checkpoint, checkpoint_every_s=checkpoint_every_s,
+        snapshot_of=([col], [0], (n_pad, B, S, C, A, W, O, T, 1, 1)))
     result["rollout"] = rollout
-    result.update(tstats)
-    so.summary("jax-wgl", result)
-    ph.lap("host")
-    # never clobber a snapshot that belongs to a DIFFERENT check (the
-    # mismatched-fingerprint case the load guard already ignores)
-    if checkpoint is not None and _checkpoint_owned(checkpoint,
-                                                    fingerprint):
-        if result.get("valid") in (True, False):
-            # decided: the snapshot is spent
-            import contextlib as _ctx
-            import os as _os
-            with _ctx.suppress(FileNotFoundError):
-                _os.unlink(checkpoint)
-        else:
-            # undecided (budget/overflow): keep a fresh snapshot so a
-            # rerun with a larger budget resumes instead of restarting
-            _save_checkpoint(checkpoint, fingerprint, carry)
-            result["checkpoint"] = checkpoint
     return result
 
 
-def _checkpoint_owned(path, fingerprint):
-    """True when path is free or holds a snapshot with this
-    fingerprint."""
-    import os as _os
-    if not _os.path.exists(path):
-        return True
-    try:
-        with np.load(path) as data:
-            return bytes(data["fingerprint"]).decode() == fingerprint
-    except Exception:  # noqa: BLE001 - corrupt file: treat as free
-        return True
-
-
-def write_snapshot(path, fingerprint, arrays):
-    """Atomically write a fingerprinted npz snapshot (shared by the
-    single-key and batched checkpoint paths)."""
-    import os as _os
-    tmp = f"{path}.tmp"     # np.savez appends .npz to names without it
-    _os.makedirs(_os.path.dirname(path) or ".", exist_ok=True)
-    np.savez_compressed(
-        tmp,
-        fingerprint=np.frombuffer(
-            fingerprint.encode(), dtype=np.uint8),
-        **arrays)
-    _os.replace(f"{tmp}.npz", path)
-
-
-def read_snapshot(path, fingerprint):
-    """Load a fingerprinted snapshot's array dict, or None when the file
-    is absent, corrupt, or belongs to a different check."""
-    import os as _os
-    if not _os.path.exists(path):
-        return None
-    try:
-        with np.load(path) as data:
-            got = bytes(data["fingerprint"]).decode()
-            if got != fingerprint:
-                return None
-            return {k: data[k] for k in data.files
-                    if k != "fingerprint"}
-    except Exception:  # noqa: BLE001 - corrupt snapshot = start fresh
-        return None
-
-
-def _save_checkpoint(path, fingerprint, carry):
-    """Atomically snapshot the search carry (stack, tables, witness
-    trackers, counters) with the input fingerprint."""
-    host = [np.asarray(x) for x in jax.device_get(carry)]
-    write_snapshot(path, fingerprint,
-                   {f"c{i}": x for i, x in enumerate(host)})
-
-
-def _load_checkpoint(path, fingerprint):
-    """Load a snapshot if it exists and matches the fingerprint; returns
-    the carry arrays or None."""
-    data = read_snapshot(path, fingerprint)
-    if data is None:
-        return None
-    return [data[f"c{i}"] for i in range(len(data))]
-
-
-def _interpret(spec, e, out, max_iters, confirm, init_state, perm=None):
+def _interpret(spec, e, out, confirm, init_state, perm=None,
+               timed_out=False, engine="jax-wgl"):
+    """One key's verdict from its harvested carry fields ``out``. A
+    search stopped by its wall budget while it still had work is
+    ``unknown`` with error ``timeout``."""
     status = int(out["status"])
     explored = int(out["explored"])
     result = {"configs_explored": explored,
               "iterations": int(out["iterations"]),
-              "engine": "jax-wgl"}
+              "engine": engine}
+    if timed_out and status == RUNNING and int(out["top"]) > 0:
+        result.update(valid="unknown", error="timeout")
+        return result
     if status == VALID:
         result["valid"] = True
         _attach_valid_witness(result, e, out, perm, spec, init_state)

@@ -21,11 +21,10 @@ The config-set stays small on low-contention histories (each return
 usually extends every config by a handful of ops), which is exactly
 when this algorithm beats WGL — and why the reference's competition
 races both: on long, low-concurrency, crash-free histories this engine
-decides in seconds where the device search pays per-iteration W*n work
-(BENCH rung 6 races them and linear wins on its home turf). Crashed
-(info) ops are its weakness — they stay open forever, so every return's
-closure explores subsets of all open infos; the budgets below turn
-that blowup into "unknown" quickly instead of burning CPU.
+decides in seconds where the device search pays per-iteration W*n work.
+Crashed (info) ops are its weakness — they stay open forever, so every
+return's closure explores subsets of all open infos; the budgets below
+turn that blowup into "unknown" quickly instead of burning CPU.
 
 Two bounds, both knossos-spirited (memory AND time), either overflow
 returning unknown rather than ever mis-deciding:

@@ -223,7 +223,7 @@ def _busy(dt: float):
     """Device-occupancy numerator for the metrics plane: every device
     closure dispatch brackets its synced wall here, the same counter
     shape ``wgl.device_busy_s`` gives the search engines, so duty-cycle
-    readers (bench rung 15, obs/merge) see closure compute too."""
+    readers (obs/merge) see closure compute too."""
     from .. import obs
     obs.inc("txn.closure_busy_s", float(dt), engine="txn-closure")
 

@@ -240,8 +240,7 @@ def run_fleet(cells, workers, *, campaign_id=None, resume=False,
     an HA campaign fences the prior epoch with a ``forced`` takeover
     record. The ``coordinator-kill`` chaos profile SIGKILLs this
     process right after a seeded cell's lease-grant append (die-once
-    marker), which is what the HA soak and bench rung 14 recover
-    from."""
+    marker), which is what the HA soak recovers from."""
     from ..analysis import planlint, render_text, errors as diag_errors
     from . import sync as fsync
 

@@ -445,6 +445,6 @@ class Standby:
 
 def takeover_marker(campaign_id):
     """Path of the chaos coordinator-kill die-once marker (shared by
-    dispatch and the bench rung)."""
+    dispatch and the HA soak)."""
     return os.path.abspath(
         store.campaign_path(campaign_id, "chaos-coordinator-kill"))

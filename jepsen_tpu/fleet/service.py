@@ -527,7 +527,7 @@ class Coalescer:
         return item.result
 
     def stats(self):
-        """Lifetime batch counters (tests, the bench rung):
+        """Lifetime batch counters (read by the tests):
         ``occupancy`` is real segments over pow-2 key lanes across
         every dispatched batch."""
         with self._cond:

@@ -1,6 +1,6 @@
 """The one place JAX's persistent compilation cache is placed.
 
-Every entry point (``cli.run``, ``web.serve``, ``bench.py``,
+Every entry point (``cli.run``, ``web.serve``, ``benchmark/run.py``,
 ``chip_smoke.py``, ``fleet.ledger.enable_jax_cache``) and
 ``tests/conftest.py`` calls :func:`enable` before its first compile.
 

@@ -44,26 +44,15 @@ untouched (the collective code only exists when ``axis_name`` is set).
 
 from __future__ import annotations
 
-import logging
-import time as _time
-
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from ..checker import jax_wgl
-from ..checker.jax_wgl import (IDX_BEST_DEPTH, IDX_BEST_LIN,
-                               IDX_BEST_STATE, IDX_DROPPED, IDX_EXPLORED,
-                               IDX_IT, IDX_ITS, IDX_STATUS, IDX_TOP,
-                               RUNNING, VALID, _build_search, _plan_sizes)
+from ..checker import drive, jax_wgl
+from ..checker.jax_wgl import IDX_TOP, _build_search, _plan_sizes
 from ..obs import phases as obs_phases
-from ..obs import search as obs_search
-from .keyshard import _shard_specs, mesh_axis, shard_map_compat
-
-logger = logging.getLogger(__name__)
-
-AXIS = "search"
+from .keyshard import _mesh_runner, mesh_axis
 
 
 def check_encoded_sharded(spec, e, init_state, mesh,
@@ -97,15 +86,8 @@ def check_encoded_sharded(spec, e, init_state, mesh,
 
     # the local kernel: ONE shard of the search (K=1, its own table
     # group), with the steal ring + global-termination collectives
-    run_local = _build_search(spec.step, 1, n_pad, B, S, C, A, W, O,
-                              T, 1, NS=rollout_seeds,
-                              rollout_kernel="scan", axis_name=ax,
-                              axis_size=D, steal=steal).run_chunk
-    carry_specs, const_specs = _shard_specs(mesh)
-    run_b = jax.jit(shard_map_compat(
-        run_local.__wrapped__, mesh,
-        (carry_specs,) + const_specs, carry_specs),
-        donate_argnums=(0,))
+    run = _mesh_runner(spec.step, 1, n_pad, B, S, C, A, W, O, T, None,
+                       rollout_seeds, mesh, steal=steal)
     ph.lap("plan")
 
     # global init: the builder's init_carry for K=D shards, then only
@@ -129,125 +111,12 @@ def check_encoded_sharded(spec, e, init_state, mesh,
                                            col.ndim)), shd)
         for col in (inv32, ret32, fop, args, rets, ok_words)) + (
         jax.device_put(jnp.zeros(D, jnp.uint32), shd),)
-    ph.sync(carry)
-    ph.lap("h2d")
-
-    t0 = _time.monotonic()
-    timed_out = False
-    # sinks captured once at search start (see obs.search docstring)
-    so = obs_search.capture()
-    # padding accounting: one real history of len(e) rows in an
-    # n_pad-row plan (the D-way replication of the op columns is
-    # sharding, not padding, so it does not count as waste)
-    so.plan("jax-wgl-sharded", n_pad, len(e), n_pad)
-    it = 0
-    eff = min(chunk_iters, 32, max(1, (32 * 16384) // n_pad))
-    while True:
-        prev_it = it
-        t_chunk = _time.monotonic()
-        bound = min(it + eff, max_iters)
-        ph.lap("host")
-        carry = run_b(carry, *consts, jnp.int32(bound))
-        # device-compute bracket: sync only while phase attribution is
-        # on (otherwise the progress device_get below stays the
-        # dispatch's one sync, as before)
-        ph.sync(carry)
-        dev_s = ph.lap("device", iteration=bound)
-        # ONE batched device_get of the progress tensor (replacing the
-        # three separate per-array transfers): per-shard status/top,
-        # the iteration counter, cumulative explored, and the witness
-        # depths whose max is the deepest linearized-ok count reached
-        status, top, it_g, explored_d, bdepth = jax.device_get(
-            (carry[IDX_STATUS], carry[IDX_TOP], carry[IDX_IT],
-             carry[IDX_EXPLORED], carry[IDX_BEST_DEPTH]))
-        status = np.asarray(status)
-        top = np.asarray(top)
-        it = int(np.asarray(it_g)[0])
-        ph.lap("d2h")
-        # per-shard frontier sizes ARE the steal-ring balance signal:
-        # all work stuck on one shard = the ring is starved. Built from
-        # the arrays this poll already fetched — no extra per-chunk
-        # device round trips
-        so.heartbeat(
-            "jax-wgl-sharded", iteration=it,
-            chunk_s=_time.monotonic() - t_chunk,
-            device_s=dev_s if ph.enabled else None,
-            frontier=int(top.sum()),
-            explored=int(np.asarray(explored_d).sum()),
-            depth=max(0, int(np.asarray(bdepth).max())),
-            shard_tops=[int(t) for t in top])
-        if (status == VALID).any() or not ((status == RUNNING)
-                                           & (top > 0)).any() \
-                or it >= max_iters:
-            break
-        now = _time.monotonic()
-        per_it = max(1e-4, (now - t_chunk) / max(1, it - prev_it))
-        eff = jax_wgl._adapt_quantum(
-            chunk_iters, per_it, 3.0,
-            timeout_s - (now - t0) if timeout_s is not None else None)
-        if timeout_s is not None and now - t0 > timeout_s:
-            timed_out = True
-            break
-
-    ph.lap("host")
-    got = jax.device_get({
-        "status": carry[IDX_STATUS], "top": carry[IDX_TOP],
-        "dropped": carry[IDX_DROPPED], "explored": carry[IDX_EXPLORED],
-        "iterations": carry[IDX_ITS],
-        "best_depth": carry[IDX_BEST_DEPTH],
-        "best_lin": carry[IDX_BEST_LIN],
-        "best_state": carry[IDX_BEST_STATE]})
-    tstats = jax_wgl.table_stats(carry)
-    ph.lap("d2h")
-    status = np.asarray(got["status"])
-    top = np.asarray(got["top"])
-    explored = np.asarray(got["explored"])
-    result = {"configs_explored": int(explored.sum()),
-              "iterations": int(np.asarray(got["iterations"]).max()),
-              "engine": "jax-wgl-sharded", "shards": D,
-              "shard_explored": [int(x) for x in explored],
-              **tstats}
-
-    def _done(result):
-        so.summary("jax-wgl-sharded", result,
-                   shard_explored=result["shard_explored"])
-        ph.lap("host")
-        return result
-
-    def _merged_slots():
-        # every shard's TOPK witness slots as one slot group (the
-        # decoder sorts by depth), so witness decoding matches the
-        # single-device engine's exactly
-        return {"best_depth": np.asarray(got["best_depth"]).reshape(-1),
-                "best_lin": np.asarray(got["best_lin"])
-                .reshape(D * jax_wgl.TOPK, -1),
-                "best_state": np.asarray(got["best_state"])
-                .reshape(D * jax_wgl.TOPK, -1)}
-
-    if (status == VALID).any():
-        result["valid"] = True
-        # the winning shard's slot carries the full linearization: emit
-        # the same normalized witness as the single-device VALID path
-        jax_wgl._attach_valid_witness(result, e, _merged_slots(), perm,
-                                      spec, init_state)
-        return _done(result)
-    if timed_out and ((status == RUNNING) & (top > 0)).any():
-        result.update(valid="unknown", error="timeout")
-        return _done(result)
-    # an empty-everywhere, nothing-dropped state is a sound exhaustion
-    # proof no matter when it was reached (even on the last allowed
-    # iteration -- the single-device _interpret has no it guard either)
-    exhausted = not (top > 0).any()
-    dropped = bool(np.asarray(got["dropped"]).any())
-    if exhausted and not dropped:
-        result["valid"] = False
-        jax_wgl._attach_witness(result, e, _merged_slots(), perm, spec,
-                                init_state)
-        return _done(result)
-    result.update(valid="unknown",
-                  error="stack-overflow" if dropped
-                  else "max-configs-exceeded")
-    return _done(result)
+    result, = drive.drive(
+        ph, "jax-wgl-sharded", spec, [(e, init_state, perm)], carry,
+        consts, run, max_iters=max_iters, chunk_iters=chunk_iters,
+        timeout_s=timeout_s, mesh=mesh)
+    result["shards"] = D
+    return result
 
 
 def check_history_sharded(spec, history, mesh, **kw):

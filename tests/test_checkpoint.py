@@ -178,3 +178,54 @@ def test_batch_checkpoint_survives_budget_change(tmp_path):
                                  checkpoint=ck)
         assert r2[0]["valid"] in (True, False)
         assert not os.path.exists(ck)
+
+
+def test_single_key_snapshot_of_the_old_layout_is_kept_and_not_read(
+        tmp_path):
+    """A snapshot in the single-key layout of earlier builds (the carry
+    alone, fingerprinted over the prepared columns) sits at the path.
+    The check ignores it, leaves it as it is, and checkpoints nothing.
+    The old carry claims success, so a misread would answer True."""
+    import hashlib
+
+    import numpy as np
+
+    from jepsen_tpu.checker import drive
+
+    rng = random.Random(4)
+    h = corrupt(rng, random_history(rng, "cas-register", 6, 120, 0.05))
+    for o in h:
+        if o["type"] == "ok" and o["f"] == "read" \
+                and o.get("value") is not None:
+            o["value"] = o["value"] % 4
+    e, st = cas_register_spec.encode(h)
+    want = jax_wgl.check_encoded(cas_register_spec, e, st)
+    assert want["valid"] is False
+
+    kind, prep = jax_wgl._prepare_search(cas_register_spec, e, st)
+    assert kind == "search"
+    (_perm, inv32, ret32, fop, args, rets, ok_words, init_state, n_pad,
+     C, A, S) = prep
+    B, W, O, T = jax_wgl._plan_sizes(n_pad, S, C)
+    old = hashlib.sha256()
+    old.update(jax_wgl.CARRY_LAYOUT.encode())
+    old.update(cas_register_spec.name.encode())
+    for a in (inv32, ret32, fop, args, rets, ok_words, init_state,
+              np.asarray([n_pad, B, S, C, W, O, T], np.int64)):
+        old.update(np.ascontiguousarray(a).tobytes())
+    carry = [np.array(x) for x in jax_wgl._build_search(
+        cas_register_spec.step, 1, n_pad, B, S, C, A, W, O, T)
+        .init_carry(init_state[None])]
+    carry[jax_wgl.IDX_STATUS][:] = jax_wgl.VALID
+    ck = str(tmp_path / "frontier.npz")
+    drive.write_snapshot(ck, old.hexdigest(),
+                         {f"c{i}": x for i, x in enumerate(carry)})
+    before = open(ck, "rb").read()
+
+    r = jax_wgl.check_encoded(cas_register_spec, e, st, chunk_iters=1,
+                              max_configs=W, checkpoint=ck)
+    assert r["valid"] == "unknown" and "checkpoint" not in r
+    assert open(ck, "rb").read() == before
+    r = jax_wgl.check_encoded(cas_register_spec, e, st, checkpoint=ck)
+    assert r["valid"] is False
+    assert open(ck, "rb").read() == before

@@ -87,6 +87,23 @@ def test_linearizable_failure_renders_witness():
     assert os.path.getsize(p) > 1000
 
 
+def test_device_engine_failures_render_into_each_keys_subdirectory():
+    """Two invalid keys checked by the device engine, as independent's
+    per-key path checks them: each key's linear.png lands in its own
+    subdirectory, where the caller's options put it."""
+    test = _test_map()
+    checker = ck.linearizable({"model": "register",
+                               "algorithm": "jax-wgl"})
+    for k in (0, 1):
+        res = checker.check(test, _invalid_register_history(),
+                            {"subdirectory": ["independent", k]})
+        assert res["valid"] is False
+    for k in (0, 1):
+        p = store.path(test, "independent", k, "linear.png")
+        assert os.path.getsize(p) > 1000
+    assert not os.path.exists(store.path(test, "linear.png"))
+
+
 def test_render_analysis_returns_none_without_witness():
     assert linear_report.render_analysis(
         _test_map(), _invalid_register_history(), {"valid": False}) is None

@@ -137,3 +137,31 @@ def test_sharded_timeout_returns_unknown():
                               chunk_iters=1)
     assert r["valid"] == "unknown"
     assert r["error"] == "timeout"
+
+
+def test_repeated_sharded_check_compiles_nothing():
+    """The steal ring's shard_map runner is built once per shape: a
+    second search of the same shape compiles nothing."""
+    mesh = _mesh(4)
+    spec = models.cas_register_spec
+    rng = random.Random(45100)
+    hist = _inrange(corrupt(rng, random_history(
+        rng, "cas-register", n_procs=6, n_ops=160, crash_p=0.05)))
+    e, st = spec.encode(hist)
+    compiles = []
+
+    def on_duration(event, _secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        first = check_encoded_sharded(spec, e, st, mesh, chunk_iters=4)
+        n_first = len(compiles)
+        second = check_encoded_sharded(spec, e, st, mesh, chunk_iters=4)
+    finally:
+        jax.monitoring.clear_event_listeners()
+    assert len(compiles) == n_first, \
+        f"{len(compiles) - n_first} compiles in the second search"
+    assert second["valid"] == first["valid"]
+    assert second["shard_explored"] == first["shard_explored"]

@@ -10,9 +10,10 @@ one key-salted dedup table and one flat scatter per structure per iteration.
 Scale-out: with a 1-D ``Mesh`` the same kernel runs under ``shard_map`` --
 keys shard over the mesh axis, and every carry element (including the dedup
 tables, which carry a leading group axis sized to the mesh) shards with
-them, so each device runs its shard's searches independently over ICI-local
-memory with no collectives in the hot loop (embarrassingly parallel, the
-right layout for this workload; SURVEY.md section 5).
+them and is built on the device that holds it, so each device runs its
+shard's searches independently over ICI-local memory with no collectives
+in the hot loop (embarrassingly parallel, the right layout for this
+workload; SURVEY.md section 5).
 
 Keys finish at different times; the host loop (``checker/drive.py``)
 polls per-key status between bounded chunks, harvests finished keys, and
@@ -30,6 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..checker import drive, jax_wgl
 from ..checker.jax_wgl import (INF32, N_CARRY, _bucket, _build_search,
                                _encode_arrays, _plan_sizes,
@@ -96,30 +98,37 @@ def mesh_axis(mesh):
 
 
 @functools.lru_cache(maxsize=64)
-def _mesh_runner(step, k_local, n_pad, B, S_pad, C, A, W, O, T, R, NS,
+def _mesh_search(step, k_local, n_pad, B, S_pad, C, A, W, O, T, R, NS,
                  mesh, steal=None):
-    """run_chunk under ``shard_map`` over ``mesh``, built once per shape:
-    a fresh ``jax.jit`` per check or per compaction would compile again
-    every time. The kernel sees LOCAL shapes: ``k_local`` rows and one
-    table group per device. ``steal`` (the hand-off size) makes it the
-    steal ring's one search over the mesh; without it the rows are keys
-    and the chips share no collective. The replication check is off:
-    the ring's collectives are not replicated."""
+    """``(init_carry, run_chunk)`` under ``shard_map`` over ``mesh``,
+    built once per shape: a fresh ``jax.jit`` per check or per
+    compaction would compile again every time. Both see LOCAL shapes:
+    ``k_local`` rows and one table group per device, which is exactly
+    that device's shard of the full-width carry, so the init builds
+    each chip's rows where they live and nothing goes through the host.
+    ``steal`` (the hand-off size) makes it the steal ring's one search
+    over the mesh; without it the rows are keys and the chips share no
+    collective. The replication check is off: the ring's collectives
+    are not replicated."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
     ax = mesh_axis(mesh)
     ring = {} if steal is None else dict(
         axis_name=ax, axis_size=int(mesh.shape[ax]), steal=steal)
-    run_local = _build_search(step, k_local, n_pad, B, S_pad, C, A, W, O,
-                              T, 1, R=R, NS=NS, rollout_kernel="scan",
-                              **ring).run_chunk
+    local = _build_search(step, k_local, n_pad, B, S_pad, C, A, W, O, T,
+                          1, R=R, NS=NS, rollout_kernel="scan", **ring)
     # every carry element and op column shards over the axis; the
     # dispatch bound is replicated
-    specs = (tuple(P(ax) for _ in range(N_CARRY)),) + (P(ax),) * 7 + (P(),)
-    return jax.jit(shard_map(run_local.__wrapped__, mesh=mesh,
-                             in_specs=specs, out_specs=specs[0],
-                             check_vma=False),
-                   donate_argnums=(0,))
+    keyed = tuple(P(ax) for _ in range(N_CARRY))
+    specs = (keyed,) + (P(ax),) * 7 + (P(),)
+    init = jax.jit(shard_map(local.init_carry, mesh=mesh,
+                             in_specs=(P(ax),), out_specs=keyed,
+                             check_vma=False))
+    run = jax.jit(shard_map(local.run_chunk.__wrapped__, mesh=mesh,
+                            in_specs=specs, out_specs=keyed,
+                            check_vma=False),
+                  donate_argnums=(0,))
+    return init, run
 
 
 def check_batch_encoded(spec, pairs, max_configs=50_000_000,
@@ -265,8 +274,14 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
         (spec.name, K, W, n_pad, B, S_pad, C, A, O, T, G, R_batch,
          rollout_seeds, mesh is not None)))
     ph.lap("plan")
-    consts = tuple(jnp.asarray(np.stack([c[i] for c in cols]))
-                   for i in range(7)) + (jnp.asarray(np.asarray(salts)),)
+    put = jnp.asarray
+    if mesh is not None:
+        # the host columns go straight to the chips that hold their rows
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        keyed = NamedSharding(mesh, P(mesh_axis(mesh)))
+        put = functools.partial(jax.device_put, device=keyed)
+    consts = tuple(put(np.stack([c[i] for c in cols]))
+                   for i in range(7)) + (put(np.asarray(salts)),)
     init_states = consts[6]
     consts = consts[:6] + (consts[7],)   # drop states, keep salt
 
@@ -290,19 +305,24 @@ def check_batch_encoded(spec, pairs, max_configs=50_000_000,
                                  Wc, O, T, G, R=R_batch,
                                  NS=rollout_seeds,
                                  rollout_kernel="scan").run_chunk
-        return _mesh_runner(spec.step, Kc // G, n_pad, B, S_pad, C, A,
-                            Wc, O, T, R_batch, rollout_seeds, mesh)
+        return _mesh_search(spec.step, Kc // G, n_pad, B, S_pad, C, A,
+                            Wc, O, T, R_batch, rollout_seeds, mesh)[1]
 
-    init_carry = _build_search(
-        spec.step, K, n_pad, B, S_pad, C, A, W, O, T, G, R=R_batch,
-        NS=rollout_seeds, rollout_kernel="scan").init_carry
+    if mesh is None:
+        init_carry = _build_search(
+            spec.step, K, n_pad, B, S_pad, C, A, W, O, T, G, R=R_batch,
+            NS=rollout_seeds, rollout_kernel="scan").init_carry
+    else:
+        # each chip builds its own rows where they live: no chip holds
+        # the full width, and no carry element passes through the host
+        init_carry = _mesh_search(spec.step, K // G, n_pad, B, S_pad, C,
+                                  A, W, O, T, R_batch, rollout_seeds,
+                                  mesh)[0]
     run = build_runner(K)
     carry = init_carry(init_states)
     if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        keyed = NamedSharding(mesh, P(mesh_axis(mesh)))
-        consts = tuple(jax.device_put(x, keyed) for x in consts)
-        carry = tuple(jax.device_put(np.asarray(x), keyed) for x in carry)
+        obs.inc("keyshard.sharded_init_bytes",
+                sum(x.nbytes for x in carry), chips=G)
     # the distinct tenants among the searched keys
     n_owners = len({str(owners[k]) for k in live}) \
         if owners is not None else None

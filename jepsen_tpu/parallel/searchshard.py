@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from ..checker import drive, jax_wgl
 from ..checker.jax_wgl import IDX_TOP, _build_search, _plan_sizes
 from ..obs import phases as obs_phases
-from .keyshard import _mesh_runner, mesh_axis
+from .keyshard import _mesh_search, mesh_axis
 
 
 def check_encoded_sharded(spec, e, init_state, mesh,
@@ -86,8 +86,8 @@ def check_encoded_sharded(spec, e, init_state, mesh,
 
     # the local kernel: ONE shard of the search (K=1, its own table
     # group), with the steal ring + global-termination collectives
-    run = _mesh_runner(spec.step, 1, n_pad, B, S, C, A, W, O, T, None,
-                       rollout_seeds, mesh, steal=steal)
+    run = _mesh_search(spec.step, 1, n_pad, B, S, C, A, W, O, T, None,
+                       rollout_seeds, mesh, steal=steal)[1]
     ph.lap("plan")
 
     # global init: the builder's init_carry for K=D shards, then only

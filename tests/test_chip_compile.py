@@ -147,14 +147,47 @@ def test_keyed_mesh_chunk_compiles_for_v5e_2x2(topo):
              + [((K, B), jnp.uint32), ((K,), jnp.uint32)]]
     bound = jax.ShapeDtypeStruct((), jnp.int32,
                                  sharding=NamedSharding(mesh, P()))
-    run = keyshard._mesh_runner(spec.step, k_local, n, B, S, C, A, W, O,
-                                T, 0, 1, mesh)
+    _, run = keyshard._mesh_search(spec.step, k_local, n, B, S, C, A, W,
+                                   O, T, 0, 1, mesh)
     with _no_persistent_cache():
         text = run.lower(tuple(args[:len(carry)]), *args[len(carry):],
                          bound).compile().as_text()
     for op in ("all-reduce", "all-gather", "collective-permute",
                "all-to-all", "reduce-scatter"):
         assert op not in text
+
+
+def test_keyed_mesh_init_compiles_for_v5e_2x2(topo):
+    """The key batch's initial carry built under ``shard_map`` over the
+    four chips of a v5e host, at the 4096-key register cell's widths
+    (1024 keys a chip): no collective, and each chip's output is its
+    own quarter of the full-width carry."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from jepsen_tpu.parallel import keyshard
+    k_local, n, B, C, W, O, T = 1024, 64, 2, 16, 32, 32768, 1 << 20
+    mesh = Mesh(np.array(topo.devices[:4]), ("keys",))
+    G = mesh.devices.size
+    K = k_local * G
+    full = jax_wgl._build_search(spec.step, K, n, B, S, C, A, W, O, T, G,
+                                 R=0, NS=1, rollout_kernel="scan")
+    carry = jax.eval_shape(full.init_carry,
+                           jax.ShapeDtypeStruct((K, S), jnp.int32))
+    init, _ = keyshard._mesh_search(spec.step, k_local, n, B, S, C, A, W,
+                                    O, T, 0, 1, mesh)
+    states = jax.ShapeDtypeStruct((K, S), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("keys")))
+    with _no_persistent_cache():
+        compiled = init.lower(states).compile()
+    text = compiled.as_text()
+    for op in ("all-reduce", "all-gather", "collective-permute",
+               "all-to-all", "reduce-scatter"):
+        assert op not in text
+    # a quarter of ~2.7 GB, give or take the chip's buffer alignment
+    nbytes = sum(np.prod(c.shape) * c.dtype.itemsize for c in carry)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert nbytes // G <= out < nbytes // G + (1 << 16)
 
 
 def test_streamlin_solo_fold_compiles_for_v5e(one_chip):

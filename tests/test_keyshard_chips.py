@@ -163,3 +163,145 @@ def test_two_axis_mesh_is_refused_with_its_axes():
         check_batch_histories(cas_register_spec, hists, mesh=mesh)
     with pytest.raises(ValueError, match=r"\('a', 'b'\)"):
         check_history_sharded(cas_register_spec, hists[0], mesh)
+
+
+# ---------------------------------------------------------------------------
+# the mesh batch's initial carry, built on the chips that hold it
+
+def _old_mesh_setup(spec, cols, salts, plan, mesh):
+    """The mesh set-up as it was before the carry was built in place:
+    the full-width init on the default device, then every element
+    pulled to the host and pushed out to the ``keys`` sharding. The
+    oracle of the carry and op columns the batch builds now."""
+    import numpy as np
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from jepsen_tpu.checker.jax_wgl import _build_search
+    n_pad, B, S_pad, C, A, W, O, T, G, K = plan
+    consts = tuple(jnp.asarray(np.stack([c[i] for c in cols]))
+                   for i in range(7)) + (jnp.asarray(np.asarray(salts)),)
+    init_states = consts[6]
+    consts = consts[:6] + (consts[7],)
+    R = 0 if n_pad <= 64 else min(256, n_pad)
+    carry = _build_search(spec.step, K, n_pad, B, S_pad, C, A, W, O, T, G,
+                          R=R, NS=1, rollout_kernel="scan").init_carry(
+                              init_states)
+    keyed = NamedSharding(mesh, P("keys"))
+    consts = tuple(jax.device_put(x, keyed) for x in consts)
+    carry = tuple(jax.device_put(np.asarray(x), keyed) for x in carry)
+    return carry, consts
+
+
+def _handed_to_drive(monkeypatch, hists, **kw):
+    """What ``check_batch_encoded`` hands the host loop, copied to the
+    host with each element's sharding; the search itself never runs."""
+    import numpy as np
+
+    from jepsen_tpu.checker import drive
+    from jepsen_tpu.parallel import check_batch_histories
+    seen = {}
+
+    def grab(xs):
+        return [(np.asarray(x), x.dtype, x.sharding) for x in xs]
+
+    def fake_drive(ph, engine, spec, keys, carry, consts, run, **dkw):
+        seen.update(keys=len(keys), carry=grab(carry),
+                    consts=grab(consts), snapshot_of=dkw["snapshot_of"])
+        return [None] * len(keys)
+
+    monkeypatch.setattr(drive, "drive", fake_drive)
+    check_batch_histories(cas_register_spec, hists, **kw)
+    return seen
+
+
+def _assert_same(got, want):
+    assert len(got) == len(want)
+    for i, ((g, gdt, gsh), w) in enumerate(zip(got, want)):
+        assert gdt == w.dtype, f"element {i}"
+        assert g.shape == w.shape, f"element {i}"
+        assert gsh.is_equivalent_to(w.sharding, w.ndim), f"element {i}"
+        assert (g == jax.device_get(w)).all(), f"element {i}"
+
+
+@pytest.mark.parametrize("chips", [2, 4])
+@pytest.mark.parametrize("n_keys, dummies", [(4, False), (5, True)])
+def test_mesh_carry_equals_full_width_init_put_to_mesh(
+        monkeypatch, chips, n_keys, dummies):
+    if len(jax.devices()) < chips:
+        pytest.skip(f"needs {chips} devices")
+    mesh = keys_mesh(chips)
+    hists = keyed_cas_history(n_keys=n_keys)[0]
+    seen = _handed_to_drive(monkeypatch, hists, mesh=mesh)
+    cols, salts, plan = seen["snapshot_of"]
+    assert (plan[-1] > seen["keys"]) is dummies    # K rows, dummies last
+    carry, consts = _old_mesh_setup(cas_register_spec, cols, salts, plan,
+                                    mesh)
+    _assert_same(seen["carry"], carry)
+    _assert_same(seen["consts"], consts)
+
+
+def test_mesh_check_builds_no_full_width_init(history, monkeypatch):
+    from jepsen_tpu import obs
+    from jepsen_tpu.checker import drive
+    from jepsen_tpu.checker.jax_wgl import IDX_TOP
+    from jepsen_tpu.parallel import check_batch_histories, keyshard
+    rows, handed = [], []
+    real_build, real_drive = keyshard._build_search, drive.drive
+
+    def recording_build(*a, **kw):
+        s = real_build(*a, **kw)
+
+        def init_carry(states):
+            rows.append(states.shape[0])
+            return s.init_carry(states)
+        return s._replace(init_carry=init_carry)
+
+    def recording_drive(ph, engine, spec, keys, carry, *a, **kw):
+        handed.append((carry[IDX_TOP].shape[0],
+                       sum(x.nbytes for x in carry)))
+        return real_drive(ph, engine, spec, keys, carry, *a, **kw)
+
+    compiles = []
+
+    def on_duration(event, _secs, **_):
+        if event == COMPILE_EVENT:
+            compiles.append(event)
+
+    monkeypatch.setattr(keyshard, "_build_search", recording_build)
+    monkeypatch.setattr(drive, "drive", recording_drive)
+    keyshard._mesh_search.cache_clear()
+    hists = history[0][:16]
+    reg = obs.Registry()
+    try:
+        with obs.sink_scope(None, reg):
+            check_batch_histories(cas_register_spec, hists,
+                                  mesh=keys_mesh(4), chunk_iters=1)
+            n_rows, n_misses = len(rows), \
+                keyshard._mesh_search.cache_info().misses
+            jax.monitoring.register_event_duration_secs_listener(
+                on_duration)
+            check_batch_histories(cas_register_spec, hists,
+                                  mesh=keys_mesh(4), chunk_iters=1)
+            jax.monitoring.clear_event_listeners()
+            misses = keyshard._mesh_search.cache_info().misses
+            one_device = obs.Registry()
+            with obs.sink_scope(None, one_device):
+                check_batch_histories(cas_register_spec, hists,
+                                      chunk_iters=1)
+    finally:
+        jax.monitoring.clear_event_listeners()
+        keyshard._mesh_search.cache_clear()
+    (K, nbytes), again = handed[0], handed[1]
+    assert again == (K, nbytes) and K >= 8
+    # every init the mesh checks called was one chip's rows
+    assert rows and set(rows[:n_rows]) == {K // 4}
+    # the repeated check traced, built and compiled nothing new
+    assert len(rows) == n_rows + 1 and rows[-1] == K    # the one device
+    assert misses == n_misses
+    assert not compiles, f"{len(compiles)} compiles in the second check"
+    # the carry's bytes, once a mesh check; nothing off the mesh
+    assert reg.counter_value("keyshard.sharded_init_bytes",
+                             chips="4") == 2 * nbytes
+    assert not [k for k in one_device.snapshot()["counters"]
+                if k.startswith("keyshard.")]
